@@ -244,6 +244,8 @@ def main(rows: list | None = None, smoke: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny tables, short straggler delay (CI: exercises "

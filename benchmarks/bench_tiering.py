@@ -264,6 +264,8 @@ def main(rows: list | None = None, smoke: bool = False, reps: int = 3,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small tables, 1 rep (CI)")

@@ -45,4 +45,6 @@ def main(rows: list | None = None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -24,6 +24,7 @@ from repro.analytics import (
     reference_query_numpy,
     synth_table,
 )
+from repro.analytics.query import ORACLE_RTOL, oracle_relative_error
 from repro.analytics.simulator import calibrated_rates
 from repro.analytics.table import distribute
 
@@ -44,10 +45,10 @@ def main():
         got, runtime = execute_query_runtime(
             fact_dist, dim_dist, QueryStrategy(strat), workflow=wf,
             invoker="threads")
-        err = np.abs(got - ref).max()
-        print(f"\n=== strategy {strat}: group-sum max err vs numpy oracle "
-              f"{err:.2e} ===")
-        assert err < 1e-3, strat
+        err = oracle_relative_error(got, ref)
+        print(f"\n=== strategy {strat}: group-sum relative err vs numpy "
+              f"oracle {err:.2e} (limit {ORACLE_RTOL:g}) ===")
+        assert err <= ORACLE_RTOL, strat
         run = wf.last_run
         print("decision sequence (bound in order, join late-bound on the "
               "observed post-filter scan output):")
@@ -86,4 +87,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

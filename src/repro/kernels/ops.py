@@ -4,9 +4,11 @@ Every primitive the analytics operators and the serverless function library
 touch routes through here: attention for the model plane, and the
 partition / join / aggregate primitives for the analytics plane. Each entry
 dispatches to the fastest available implementation — a Pallas kernel on TPU
-(``partition_histogram``/``partition_scatter``), a jitted single-pass jnp
-computation elsewhere — so callers never carry their own ad-hoc ``jax.jit``
-wrappers and every call site shares one compilation cache.
+(``partition_histogram``/``partition_destinations``/``fused_probe``), a
+jitted single-pass jnp computation elsewhere — so callers never carry their
+own ad-hoc ``jax.jit`` wrappers and every call site shares one compilation
+cache. On a TPU the analytics entries never fall back to the jnp path; each
+``kernel/*`` span records which path ran (``path="pallas"|"jit"``).
 
 Shape classes: the partition-grouping entry point (``grouping_indices``)
 pads its input to the next power of two before hitting the jitted body, so
@@ -29,9 +31,11 @@ from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.decode_attention import decode_attention as _decode
 from repro.kernels.partition import (
     fused_probe as _fused_probe,
+    partition_destinations as _destinations,
     partition_histogram as _hist,
     partition_scatter as _scatter,
 )
+from repro.obs.tracer import get_tracer
 
 HASH_MULT = jnp.uint32(0x9E3779B1)   # Knuth multiplicative hash
 EMPTY = jnp.int32(-1)
@@ -89,33 +93,39 @@ def partition_permutation(keys: jax.Array, num_partitions: int):
     return order, counts, pids
 
 
-def partition_histogram(part_ids, num_partitions: int, block: int = 1024,
+def _kernel_path(force_kernel: bool) -> str:
+    """``"pallas"`` on TPU (or when a test forces the interpret-mode
+    kernel), ``"jit"`` for the jnp path elsewhere — the ``path`` attribute
+    every ``kernel/*`` span carries."""
+    return "pallas" if on_tpu() or force_kernel else "jit"
+
+
+def partition_histogram(part_ids, num_partitions: int,
                         force_kernel: bool = False):
-    """Per-partition row counts. Pallas per-block histograms on TPU (summed
-    here), jnp bincount elsewhere. Handles the n == 0 and
-    block-non-divisible edges the raw kernel asserts on."""
+    """Per-partition row counts: the Pallas histogram on TPU, jnp bincount
+    elsewhere. The kernel pads any row count to its block, so on a TPU it
+    serves every ``n > 0``."""
     n = int(part_ids.shape[0])
     if n == 0:
         return jnp.zeros((num_partitions,), jnp.int32)
-    if (on_tpu() or force_kernel) and n % min(block, n) == 0:
-        hist = _hist(part_ids, num_partitions, block=block,
-                     interpret=not on_tpu())
-        return jnp.sum(hist, axis=0).astype(jnp.int32)
-    return ref.partition_histogram_ref(part_ids, num_partitions)
+    path = _kernel_path(force_kernel)
+    with get_tracer().span("kernel/histogram", "kernel", rows=n,
+                           buckets=num_partitions, path=path):
+        if path == "pallas":
+            return _hist(part_ids, num_partitions, interpret=not on_tpu())
+        return ref.partition_histogram_ref(part_ids, num_partitions)
 
 
-def partition_scatter(rows, part_ids, num_partitions: int, block: int = 1024,
+def partition_scatter(rows, part_ids, num_partitions: int,
                       force_kernel: bool = False):
-    """Stable grouping of 2-D rows by partition id -> (grouped, offsets).
-
-    Pallas kernel on TPU when the row count divides the block size; the
-    jnp reference otherwise (including the empty input the kernel's grid
-    cannot express)."""
+    """Stable grouping of 2-D rows by partition id -> (grouped, offsets):
+    the Pallas destinations kernel plus an XLA scatter on TPU, the jnp
+    reference elsewhere."""
     n = int(rows.shape[0])
     if n == 0:
         return rows, jnp.zeros((num_partitions,), jnp.int32)
-    if (on_tpu() or force_kernel) and n % min(block, n) == 0:
-        return _scatter(rows, part_ids, num_partitions, block=block,
+    if _kernel_path(force_kernel) == "pallas":
+        return _scatter(rows, part_ids, num_partitions,
                         interpret=not on_tpu())
     return ref.partition_scatter_ref(rows, part_ids, num_partitions)
 
@@ -175,6 +185,22 @@ def _grouping_padded(pids_padded: jax.Array, num_partitions: int):
     return order, offsets
 
 
+@partial(jax.jit, static_argnames=("num_partitions", "interpret"))
+def _grouping_pallas(pids_padded: jax.Array, num_partitions: int,
+                     interpret: bool = False):
+    """The Pallas twin of ``_grouping_padded``: the kernel's destinations
+    over ``num_partitions + 1`` buckets (the sentinel bucket last), then
+    one XLA scatter inverts them into the grouping permutation. The
+    exclusive offsets of the ``P + 1`` buckets are exactly
+    ``[0, c0, c0+c1, ..., real-row count]``."""
+    dest, offsets = _destinations(pids_padded, num_partitions + 1,
+                                  interpret=interpret)
+    n = pids_padded.shape[0]
+    order = jnp.zeros((n,), jnp.int32).at[dest].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+    return order, offsets
+
+
 def grouping_indices(part_ids, num_partitions: int,
                      force_kernel: bool = False):
     """One-call shuffle grouping: ``(order, offsets)`` for a partition-id
@@ -184,12 +210,10 @@ def grouping_indices(part_ids, num_partitions: int,
     This is the single-pass replacement for the per-bucket
     ``np.nonzero``/``take`` loop: one device computation yields every
     bucket's membership at once. Inputs are padded to a power-of-two shape
-    class before the jitted body (or the Pallas scatter on TPU) runs, so
-    heterogeneous per-partition row counts share a handful of compiled
-    executables.
+    class before the jitted body (or the Pallas destinations kernel on
+    TPU) runs, so heterogeneous per-partition row counts share a handful
+    of compiled executables.
     """
-    from repro.obs.tracer import get_tracer
-
     n = int(part_ids.shape[0])
     if n == 0:
         return (jnp.zeros((0,), jnp.int32),
@@ -199,23 +223,20 @@ def grouping_indices(part_ids, num_partitions: int,
     shape_class = (n_pad, num_partitions)
     fresh = shape_class not in _SHAPE_CLASSES
     _SHAPE_CLASSES.add(shape_class)
+    path = _kernel_path(force_kernel)
     with get_tracer().span("kernel/grouping", "kernel", rows=n,
                            shape_class=n_pad, buckets=num_partitions,
-                           compile="fresh" if fresh else "cached"):
+                           compile="fresh" if fresh else "cached",
+                           path=path):
         pids = jnp.asarray(part_ids, jnp.int32)
         if n_pad != n:
             pids = jnp.concatenate(
                 [pids, jnp.full((n_pad - n,), num_partitions, jnp.int32)])
-        if on_tpu() or force_kernel:
-            # Pallas path: scatter the index column through the kernel — the
-            # grouped output *is* the permutation (sentinel rows land last),
-            # and the kernel's per-partition bases over num_partitions + 1
-            # buckets *are* the offsets vector ([0, c0, c0+c1, ..., n]).
-            idx = jnp.arange(n_pad, dtype=jnp.int32)[:, None]
-            grouped, part_base = _scatter(idx, pids, num_partitions + 1,
-                                          interpret=not on_tpu())
-            return grouped[:, 0][:n], part_base
-        order, offsets = _grouping_padded(pids, num_partitions)
+        if path == "pallas":
+            order, offsets = _grouping_pallas(pids, num_partitions,
+                                              interpret=not on_tpu())
+        else:
+            order, offsets = _grouping_padded(pids, num_partitions)
         return order[:n], offsets
 
 
@@ -288,10 +309,7 @@ def grouping_cache_size() -> int:
     """Compiled-executable count of the jitted grouping body — the CI
     smoke benchmark asserts this stays at one per (shape class, bucket
     count), i.e. no per-partition recompilation."""
-    try:
-        return int(_grouping_padded._cache_size())
-    except AttributeError:  # pragma: no cover - older/newer jax internals
-        return -1
+    return int(_grouping_padded._cache_size())
 
 
 # -- joins ---------------------------------------------------------------------
@@ -379,17 +397,18 @@ def hash_join_indices(probe_keys: jax.Array, build_keys: jax.Array,
 
 # -- fused partition+probe (the pipelined join's bucket primitive) -------------
 
-# build sides at or below this padded row count keep the kernel's
-# (probe-block, build) one-hot comfortably inside VMEM (~2 MB of int32 at
-# 128 x 4096); larger buckets take the jitted sorted-search fallback
+# build sides at or below this padded row count ride in the kernel's SMEM
+# (two int32 columns, 32 KiB at 4096 rows) and cost one pass over the probe
+# block per build row; larger buckets take the jitted sorted-search body
 FUSED_VMEM_ROWS = 4096
 
 
 @partial(jax.jit, static_argnames=("num_groups",))
 def _fused_probe_padded(pk, v0, v1, bk, bc, bv, num_groups: int):
-    """Jitted fallback over shape-class-padded buckets: sort the build side
-    once, binary-search every probe key, mask invalid (padding) build rows
-    through the sort so a sentinel collision can never fake a match."""
+    """Jitted sorted-search body over shape-class-padded buckets: sort the
+    build side once, binary-search every probe key, mask invalid (padding)
+    build rows through the sort so a sentinel collision can never fake a
+    match."""
     big = jnp.int32(2**31 - 1)
     keys = jnp.where(bv != 0, bk, big)     # park padding rows at the end
     order = jnp.argsort(keys)
@@ -413,18 +432,17 @@ def fused_probe_groups(probe_keys, v0, v1, build_keys, build_cat,
     carry group 0 / weight 0 — bit-identical to the unfused
     ``join -> where(found) -> cat % G`` pipeline (build keys unique per the
     join contract). Probe and build sides are padded to power-of-two shape
-    classes; the Pallas path runs when the build side fits the VMEM budget
-    (``FUSED_VMEM_ROWS``), the jitted sorted-search body elsewhere.
+    classes; the Pallas path runs when the build side is at most
+    ``FUSED_VMEM_ROWS`` rows, the jitted sorted-search body elsewhere.
     """
-    from repro.obs.tracer import get_tracer
-
     n = int(probe_keys.shape[0])
     m = int(build_keys.shape[0])
     if n == 0 or m == 0:
         return (np.zeros((n,), np.int32), np.zeros((n,), np.float32))
     n_pad, m_pad = _pad_len(n), _pad_len(m)
     _note_padding(n + m, n_pad + m_pad)
-    kernel_ok = (on_tpu() or force_kernel) and m_pad <= FUSED_VMEM_ROWS
+    kernel_ok = _kernel_path(force_kernel) == "pallas" and \
+        m_pad <= FUSED_VMEM_ROWS
     with get_tracer().span("kernel/fused_probe", "kernel", rows=n,
                            build_rows=m, shape_class=n_pad,
                            path="pallas" if kernel_ok else "jit"):

@@ -65,3 +65,17 @@ def partition_scatter_ref(rows: jax.Array, part_ids: jax.Array,
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                jnp.cumsum(counts)[:-1].astype(jnp.int32)])
     return rows[order], offsets
+
+
+def fused_probe_ref(pk: jax.Array, v0: jax.Array, v1: jax.Array,
+                    bk: jax.Array, bc: jax.Array, bv: jax.Array,
+                    num_groups: int):
+    """Fused probe oracle, dense: the (N, M) equality matrix of probe keys
+    against valid build keys (build keys unique among valid rows). Returns
+    ``(group, weight)`` per probe row; non-matching rows carry group 0 /
+    weight 0."""
+    match = jnp.logical_and(pk[:, None] == bk[None, :], bv[None, :] != 0)
+    found = jnp.any(match, axis=1)
+    cat = jnp.where(found, bc[jnp.argmax(match, axis=1)], 0)
+    weight = jnp.where(found, v0 * v1, jnp.float32(0.0))
+    return cat % num_groups, weight

@@ -43,7 +43,7 @@ from repro.core.decisions import DecisionContext
 from repro.training.optimizer import init_opt_state, opt_state_axes
 from repro.training.train_step import make_train_step
 from repro.launch.hlo_analysis import analyze
-from repro.compat import cost_analysis, set_mesh
+from jax import set_mesh
 
 DEFAULT_OUT = Path("experiments/dryrun")
 
@@ -187,7 +187,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 t_compile = time.time() - t0 - t_lower
 
             mem = compiled.memory_analysis()
-            cost = cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             hlo = compiled.as_text()
             parsed = analyze(hlo)
 

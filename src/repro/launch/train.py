@@ -29,7 +29,7 @@ from repro.models import init_lm
 from repro.parallel.sharding import use_rules
 from repro.parallel.strategies import make_rules, strategy_node
 from repro.training import init_opt_state, make_train_step
-from repro.compat import set_mesh
+from jax import set_mesh
 
 
 def main(argv=None):

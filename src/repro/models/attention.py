@@ -22,7 +22,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.config import ModelConfig
 from repro.models.layers import _init, apply_rope
 from repro.parallel.sharding import current_rules, logical_shard

@@ -23,7 +23,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.config import ModelConfig, MoEConfig
 from repro.models.layers import _init
 from repro.parallel.sharding import current_rules, logical_shard

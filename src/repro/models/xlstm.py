@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.config import ModelConfig, XLSTMConfig
 from repro.models.layers import _init
 from repro.models.ssm import _causal_conv

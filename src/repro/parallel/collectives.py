@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 
 
 def _quantize(x: jax.Array, bits: int = 8):

@@ -31,6 +31,11 @@ Lambada-style burst fan-out economics modeled explicitly:
   drives it from the ``elasticity_node`` decision
   (``repro.core.decisions``), whose twin lives in the cluster simulator so
   decision sequences stay plane-identical.
+* **Host CPU only.** The worker plane models function containers; the
+  accelerator belongs to the host process. A chip admits one process at
+  a time, so every worker is spawned with ``JAX_PLATFORMS=cpu`` in its
+  environment before it imports jax, whatever the host's environment
+  says, and runs its function bodies on the host CPU.
 * **Faults.** A worker that dies mid-invocation (``WorkerKillFault``
   SIGKILL, OOM, a real crash) surfaces as ``WorkerKilledError`` — an
   ``InjectedCrashError`` subclass — so the invoker's existing machinery
@@ -224,6 +229,26 @@ def _worker_metrics(ctx, t0: float, pad0, pad1) -> dict:
 # Host side: the pool and its economics
 # ---------------------------------------------------------------------------
 
+# a spawned child inherits the host's environment at ``Process.start``; the
+# lock keeps concurrent spawns from restoring each other's override early
+_SPAWN_ENV_LOCK = threading.Lock()
+
+
+def _start_on_cpu(proc) -> None:
+    """Start ``proc`` with ``JAX_PLATFORMS=cpu`` in its environment, so the
+    child pins jax to the CPU before its first import of jax (the parent
+    may hold the chip), then restore the host's environment."""
+    with _SPAWN_ENV_LOCK:
+        saved = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            proc.start()
+        finally:
+            if saved is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = saved
+
 
 class WorkerHandle:
     """One live worker subprocess plus its host-side pipe end."""
@@ -319,7 +344,7 @@ class WorkerPool:
             wid = self._ids
         proc = self._mp.Process(target=worker_main, args=(child, self.modules),
                                 daemon=True, name=f"repro-worker-{wid}")
-        proc.start()
+        _start_on_cpu(proc)
         child.close()
         if not host.poll(120):
             proc.kill()

@@ -50,6 +50,37 @@ def test_join_methods_agree_with_oracle(method):
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-3)
 
 
+def _reference_query_row_loop(fact, dim, num_groups=64):
+    """The oracle as one Python step per fact row: the reference the
+    vectorized ``reference_query_numpy`` must reproduce exactly."""
+    lookup = {int(k): int(c) for k, c in zip(np.asarray(dim["key"]),
+                                             np.asarray(dim["cat"]))}
+    out = np.zeros(num_groups)
+    for k, a, b in zip(np.asarray(fact["key"]),
+                       np.asarray(fact["v0"]).astype(np.float64),
+                       np.asarray(fact["v1"]).astype(np.float64)):
+        if a > 0 and int(k) in lookup:
+            out[lookup[int(k)] % num_groups] += a * b
+    return out
+
+
+@pytest.mark.parametrize("case", ["uniform", "duplicate_dim_keys",
+                                  "empty_dim", "no_match"])
+def test_reference_query_numpy_matches_row_loop(case):
+    fact, dim = make_tables(rows=3000, keyspace=512, dim_rows=200, seed=3)
+    if case == "duplicate_dim_keys":
+        dim = Table({"key": jnp.asarray(np.arange(200) % 50, jnp.int32),
+                     "cat": jnp.arange(200, dtype=jnp.int32)})
+    elif case == "empty_dim":
+        dim = Table({"key": jnp.zeros((0,), jnp.int32),
+                     "cat": jnp.zeros((0,), jnp.int32)})
+    elif case == "no_match":
+        dim = Table({"key": dim["key"] + 10_000, "cat": dim["cat"]})
+    got = reference_query_numpy(fact, dim, num_groups=16)
+    np.testing.assert_array_equal(
+        got, _reference_query_row_loop(fact, dim, num_groups=16))
+
+
 def test_joins_agree_with_each_other():
     fact, dim = make_tables(seed=7)
     a = np.asarray(execute_query_jax(fact, dim, method="hash"))

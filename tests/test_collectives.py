@@ -17,7 +17,7 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.parallel.collectives import compressed_allreduce
 
     mesh = jax.make_mesh((8,), ("pod",))

@@ -10,7 +10,9 @@ from _hypothesis_compat import given, settings, st
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.partition import partition_histogram, partition_scatter
+from repro.kernels.partition import (fused_probe, partition_destinations,
+                                     partition_histogram,
+                                     partition_scatter)
 
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
@@ -100,8 +102,7 @@ def test_partition_histogram(n, p, block):
     pids = jax.random.randint(jax.random.PRNGKey(1), (n,), 0, p, jnp.int32)
     hist = partition_histogram(pids, p, block=block, interpret=True)
     np.testing.assert_array_equal(
-        np.asarray(jnp.sum(hist, axis=0)),
-        np.asarray(ref.partition_histogram_ref(pids, p)))
+        np.asarray(hist), np.asarray(ref.partition_histogram_ref(pids, p)))
 
 
 @pytest.mark.parametrize("n,p,d,block", [(512, 4, 4, 128), (2048, 16, 8, 512)])
@@ -137,6 +138,43 @@ def test_partition_is_stable_grouping(seed, p):
         expect = np.nonzero(pids_np == part)[0]
         np.testing.assert_array_equal(seg, expect)
         start += counts[part]
+
+
+@pytest.mark.parametrize("n,p,block", [(1, 3, 1024), (200, 7, 1024),
+                                       (5000, 33, 2048), (3000, 512, 1024)])
+def test_partition_destinations_are_stable_grouping(n, p, block):
+    """Row counts that are not a whole block are padded inside the wrapper;
+    every destination is the row's slot in the stable grouped order, across
+    rows and grid steps."""
+    pids = jax.random.randint(jax.random.PRNGKey(n), (n,), 0, p, jnp.int32)
+    dest, offsets = partition_destinations(pids, p, block=block,
+                                           interpret=True)
+    order = np.argsort(np.asarray(pids), kind="stable")
+    expect = np.empty(n, np.int64)
+    expect[order] = np.arange(n)
+    np.testing.assert_array_equal(np.asarray(dest), expect)
+    counts = np.bincount(np.asarray(pids), minlength=p)
+    np.testing.assert_array_equal(np.asarray(offsets),
+                                  np.cumsum(counts) - counts)
+
+
+@pytest.mark.parametrize("n,m,pad", [(300, 40, 0), (2048, 512, 100),
+                                     (1500, 64, 64)])
+def test_fused_probe_matches_ref_with_padding_collisions(n, m, pad):
+    """Padding build rows carry key 0 like the dispatch layer pads them; a
+    real build key 0 must still match, and padding must never match."""
+    rng = np.random.default_rng(n + m)
+    keys = np.concatenate([[0], 1 + rng.permutation(4 * m - 1)[:m - 1]])
+    bk = jnp.asarray(np.concatenate([keys, np.zeros(pad, int)]), jnp.int32)
+    bc = jnp.asarray(rng.integers(0, 1000, m + pad), jnp.int32)
+    bv = jnp.asarray(np.arange(m + pad) < m, jnp.int32)
+    pk = jnp.asarray(rng.integers(0, 4 * m, n), jnp.int32)
+    v0 = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    v1 = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    grp, wgt = fused_probe(pk, v0, v1, bk, bc, bv, 64, interpret=True)
+    egrp, ewgt = ref.fused_probe_ref(pk, v0, v1, bk, bc, bv, 64)
+    np.testing.assert_array_equal(np.asarray(grp), np.asarray(egrp))
+    np.testing.assert_allclose(np.asarray(wgt), np.asarray(ewgt))
 
 
 # -- dispatch-layer differentials: Pallas kernels vs ref vs numpy oracle ----------

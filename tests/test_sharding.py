@@ -143,8 +143,7 @@ def test_hlo_analyzer_matches_xla_on_straightline():
 
     spec = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     compiled = jax.jit(f).lower(spec, spec, spec).compile()
-    from repro.compat import cost_analysis
-    xla_flops = cost_analysis(compiled)["flops"]
+    xla_flops = compiled.cost_analysis()["flops"]
     parsed = analyze(compiled.as_text()).flops
     assert parsed == pytest.approx(xla_flops, rel=1e-6)
 

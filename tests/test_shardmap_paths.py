@@ -29,7 +29,7 @@ def test_moe_shard_map_matches_reference():
     import dataclasses, jax, jax.numpy as jnp
     from repro.configs import get_config
     from repro.models.moe import init_moe, moe, moe_shard_map
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.parallel.sharding import ShardingRules, use_rules
 
     cfg = get_config("granite-moe-1b-a400m", smoke=True)
@@ -64,7 +64,7 @@ def test_int8_kv_broadcast_close_and_differentiable():
     import jax, jax.numpy as jnp
     from repro.configs import get_config
     from repro.models.attention import init_attention, attention
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.parallel.sharding import ShardingRules, use_rules
 
     cfg = get_config("qwen1.5-4b", smoke=True)
@@ -104,7 +104,7 @@ def test_slstm_shard_map_matches_unsharded():
     import jax, jax.numpy as jnp
     from repro.configs import get_config
     from repro.models.xlstm import init_slstm, slstm
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.parallel.sharding import ShardingRules, use_rules
 
     cfg = get_config("xlstm-1.3b", smoke=True)
@@ -126,17 +126,13 @@ def test_slstm_shard_map_matches_unsharded():
 
 @pytest.mark.slow
 def test_pipeline_parallel_matches_plain_train_step():
-    from repro.compat import LEGACY_SHARD_MAP
-    if LEGACY_SHARD_MAP:
-        pytest.skip("pipeline needs shard_map partial-manual (axis_names) "
-                    "mode; legacy auto= lowering lacks PartitionId support")
     run("""
     import jax, jax.numpy as jnp
     from repro.configs import get_config
     from repro.core.config import OptimizerConfig, ParallelConfig, ShapeConfig
     from repro.models import init_lm
     from repro.parallel.pipeline import make_pp_train_step, pp_rules
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.parallel.sharding import ShardingRules, use_rules
     from repro.training.train_step import make_train_step, _loss_fn
     from repro.training.optimizer import init_opt_state

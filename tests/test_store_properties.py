@@ -197,7 +197,10 @@ def _check_tiered_interleaving(store: ShuffleStore, ops) -> None:
     try:
         # model: (app, stage) -> part -> writer -> (nbytes, node, tier)
         model: dict = {}
-        lost: dict = {}          # (app, stage) -> tombstoned partition ids
+        # (app, stage) -> tombstoned partition -> writers still owed: a lost
+        # partition heals only once every writer whose slice was lost has
+        # re-written it
+        lost: dict = {}
         total_read = 0
         total_remote = 0
         for op in ops:
@@ -207,7 +210,11 @@ def _check_tiered_interleaving(store: ShuffleStore, ops) -> None:
                           writer=writer)
                 model.setdefault((app, stage), {}).setdefault(
                     part, {})[writer] = (nbytes, node, "memory")
-                lost.get((app, stage), set()).discard(part)   # put heals
+                owed = lost.get((app, stage), {})
+                if part in owed:
+                    owed[part].discard(writer)
+                    if not owed[part]:
+                        del owed[part]
             elif op[0] == "delete":
                 _, app, stage = op
                 freed = store.delete_stage(app, stage)
@@ -236,12 +243,13 @@ def _check_tiered_interleaving(store: ShuffleStore, ops) -> None:
                 # recovers via lineage like any other)
                 assert freed == sum(b for blobs in parts.values()
                                     for b, _, _ in blobs.values())
-                if parts:
-                    lost.setdefault((app, stage), set()).update(parts)
+                for part, blobs in parts.items():
+                    lost.setdefault((app, stage), {}).setdefault(
+                        part, set()).update(blobs)
             else:   # get
                 _, app, stage, part, reader = op
                 blobs = model.get((app, stage), {}).get(part, {})
-                if not blobs and part in lost.get((app, stage), set()):
+                if part in lost.get((app, stage), {}):
                     with pytest.raises(StageLostError):
                         store.get(app, stage, part, node=reader)
                 else:
@@ -288,7 +296,7 @@ def _check_tiered_interleaving(store: ShuffleStore, ops) -> None:
             assert sum(store.sent_bytes.values()) == total_remote
             assert store.cross_node_bytes == total_remote
             for key_k, parts_k in lost.items():
-                assert store.lost_partitions(*key_k) == parts_k
+                assert store.lost_partitions(*key_k) == set(parts_k)
     finally:
         store.close()
 

@@ -133,6 +133,27 @@ def test_process_backend_query_matches_oracle_with_elastic_decision():
         rt.invoker.shutdown()
 
 
+def test_workers_run_on_host_cpu_whatever_the_host_env(monkeypatch):
+    """Spawned workers pin jax to the CPU before importing it: with the
+    host's environment asking for a TPU (which a worker could never share
+    with the host process), the query still runs in the workers and the
+    host's environment is left as it was."""
+    import os
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    fd, dd, ref = make_dist_tables(seed=11)
+    gc = GlobalController({n: 8 for n in range(4)})
+    rt = Runtime(gc, invoker="process", max_workers=1)
+    try:
+        got, _ = execute_query_runtime(fd, dd, QueryStrategy("static_merge"),
+                                       runtime=rt)
+        np.testing.assert_allclose(got, ref, atol=1e-3)
+        assert rt.invoker.pool.stats()["cold_starts"] >= 1
+    finally:
+        rt.invoker.shutdown()
+    assert os.environ["JAX_PLATFORMS"] == "tpu"
+
+
 # -- SIGKILL chaos ----------------------------------------------------------------
 
 
