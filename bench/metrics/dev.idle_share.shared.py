@@ -1,0 +1,7 @@
+"""``dev.idle_share`` of the cells whose tenants share one runtime (see
+``dev.idle_share.py``): the same reading, under its own name so that it has its
+own bound and moves the shared cells' end-to-end metric."""
+
+from benchlib.readers import load_reader
+
+read = load_reader("dev.idle_share")

@@ -1,0 +1,7 @@
+"""``disp.padding_share`` of the cells whose tenants share one runtime (see
+``disp.padding_share.py``): the same reading, under its own name so that it has its
+own bound and moves the shared cells' end-to-end metric."""
+
+from benchlib.readers import load_reader
+
+read = load_reader("disp.padding_share")
