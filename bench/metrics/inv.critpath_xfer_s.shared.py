@@ -1,0 +1,7 @@
+"""``inv.critpath_xfer_s`` of the cells whose tenants share one runtime (see
+``inv.critpath_xfer_s.py``): the same reading, under its own name so that it
+moves the shared cells' end-to-end metric."""
+
+from benchlib.readers import load_reader
+
+read = load_reader("inv.critpath_xfer_s")

@@ -25,14 +25,17 @@ and tests keep working.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.analytics.table import Table
 from repro.kernels.ops import (  # noqa: F401  (re-exported primitives)
     EMPTY,
     HASH_MULT,
     build_hash_table,
+    device_copy,
     grouping_indices,
     hash_join_indices,
+    host_copy,
     partition_ids,
     partition_permutation,
     segment_sum,
@@ -45,7 +48,7 @@ def join(probe: Table, build: Table, key: str = "key",
     """Inner-join (probe ⋈ build); returns probe columns + matched build
     columns + 'found' mask column. The index computation is one kernel
     dispatch per side (build + probe for hash, sort + merge for merge)."""
-    pk, bk = probe[key], build[key]
+    pk, bk = device_copy([probe[key], build[key]])
     if method == "hash":
         slots = build_hash_table(bk)
         idx, found = hash_join_indices(pk, bk, slots)
@@ -54,12 +57,21 @@ def join(probe: Table, build: Table, key: str = "key",
     else:
         raise ValueError(method)
     cols = dict(probe.columns)
+    host_idx = None
     for name, col in build.columns.items():
         if name == key:
             continue
+        if isinstance(col, np.ndarray):
+            # a host build side is gathered on the host: the indices come
+            # down (waiting for the join) and the gathered column goes up
+            if host_idx is None:
+                host_idx = host_copy(idx, "join_idx")
+            got = device_copy(col[host_idx])
+        else:
+            got = col[idx]
         out_name = name + (suffix if name in cols else "")
         cols[out_name] = jnp.where(
-            found if col.ndim == 1 else found[:, None], col[idx], 0)
+            found if col.ndim == 1 else found[:, None], got, 0)
     cols["found"] = found
     return Table(cols)
 

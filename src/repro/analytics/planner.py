@@ -130,14 +130,19 @@ def decide_skew(run: WorkflowRun, rows_hist, bytes_hist,
     exactly recomputed (simulator) shuffle histogram and merged
     heavy-hitter sketch — and bind it. One helper shared by both planes,
     so the profile keys (and therefore the bound sequences) cannot drift
-    between the simulator and the runtime."""
+    between the simulator and the runtime. The ``decide/skew`` span
+    carries the partition balance it decided on."""
     run.ctx.profile["skew.partition_rows"] = tuple(
         int(r) for r in rows_hist)
-    run.ctx.profile["skew.partition_bytes"] = tuple(
+    nbytes = run.ctx.profile["skew.partition_bytes"] = tuple(
         int(b) for b in bytes_hist)
-    run.ctx.profile["skew.hot_keys"] = tuple(
+    hot = run.ctx.profile["skew.hot_keys"] = tuple(
         (int(k), int(c)) for k, c in hot_keys)
-    return run.decide("skew")
+    balance = {} if not nbytes else {
+        "max_partition_bytes": max(nbytes),
+        "mean_partition_bytes": int(sum(nbytes) / len(nbytes)),
+        "hot_keys": len(hot)}
+    return run.decide("skew", **balance)
 
 
 def shuffle_skew_feedback(fact, n_join: int, filter_col: str = "v0",
@@ -849,15 +854,6 @@ class AdaptiveQueryPlan:
         nbytes = tuple(fb.get("shuffle_fact.partition_bytes", ()))
         hot = tuple(fb.get("shuffle_fact.hot_keys", ()))
         skew_d = decide_skew(self.run, rows, nbytes, hot)
-        # partition balance as counter tracks: visible in the Chrome trace
-        # next to slot occupancy and store bytes
-        from repro.obs.tracer import get_tracer
-        tr = get_tracer()
-        if tr.enabled and nbytes:
-            tr.count(f"skew/{self.app}/max_partition_bytes", max(nbytes))
-            tr.count(f"skew/{self.app}/mean_partition_bytes",
-                     int(sum(nbytes) / len(nbytes)))
-            tr.count(f"skew/{self.app}/hot_keys", len(hot))
         return self._plan_rest(runtime, skew_d)
 
     def _plan_rest(self, runtime, skew_d: Decision) -> list:

@@ -305,7 +305,7 @@ def execute_query_runtime(fact: DistTable, dim: DistTable,
         num_groups=num_groups, pc=pc,
         consolidate_threshold=consolidate_threshold, workflow=workflow,
         map_split=map_split, seed_tier=seed_tier, reuse_inputs=reuse_inputs)
-    runtime.execute(plan.initial_stages(), pc=pc, planner=plan,
+    runtime.execute(None, pc=pc, planner=plan,
                     barrier=barrier, recovery=recovery,
                     max_recoveries=max_recoveries, pipeline=pipeline)
     return runtime.result(app), runtime

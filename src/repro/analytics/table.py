@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.decisions import DataDist, partition_skew
+from repro.kernels.ops import device_copy, host_copy
 
 
 @dataclass
@@ -43,11 +44,14 @@ class Table:
         return self.columns[name]
 
     def take(self, idx) -> "Table":
+        # host columns go to the device in one explicit, traced copy
         return Table({k: jnp.take(v, idx, axis=0)
-                      for k, v in self.columns.items()})
+                      for k, v in device_copy(self.columns).items()})
 
     def mask(self, keep) -> "Table":
-        idx = jnp.nonzero(keep, size=int(np.sum(np.asarray(keep))))[0]
+        # the row count is read on the host: a wait on the device's mask
+        rows = int(np.sum(host_copy(keep, "mask_rows")))
+        idx = jnp.nonzero(keep, size=rows)[0]
         return self.take(idx)
 
     def concat(self, other: "Table") -> "Table":

@@ -182,7 +182,9 @@ class DecisionNode:
     Every binding is reported to the global ``DecisionAuditLog``
     (``repro.obs.audit``) together with the context snapshot it saw —
     profile feedback, data distributions, free slots, upstream decisions —
-    attributed to the query the calling scope bound via ``bound_app``.
+    attributed to the query the calling scope bound via ``bound_app``, and
+    timed as a ``decide/<name>`` span (category ``planner``, its ``value``
+    the bound ``func``), nested under the caller's open span.
     """
 
     def __init__(self, name: str, fn: DecisionFn,
@@ -194,14 +196,21 @@ class DecisionNode:
         self.candidates = tuple(candidates)
         self.history: deque[tuple[float, Decision]] = deque(maxlen=max_history)
 
-    def decide(self, ctx: DecisionContext) -> Decision:
-        from repro.obs.audit import get_audit_log
-        try:
-            decision = self.fn(ctx)
-        except Exception:
-            if self.fallback is None:
-                raise
-            decision = self.fallback(ctx)
+    def decide(self, ctx: DecisionContext, **span_attrs) -> Decision:
+        """Bind the node on ``ctx``; ``span_attrs`` annotate its
+        ``decide/<name>`` span (what the caller observed for it)."""
+        from repro.obs.audit import current_app, get_audit_log
+        from repro.obs.tracer import get_tracer
+        with get_tracer().span(f"decide/{self.name}", "planner",
+                               trace=current_app(), **span_attrs) as sp:
+            try:
+                decision = self.fn(ctx)
+            except Exception:
+                if self.fallback is None:
+                    raise
+                decision = self.fallback(ctx)
+            if sp is not None:
+                sp.attrs["value"] = decision.func
         self.history.append((time.monotonic(), decision))
         get_audit_log().record(self, ctx, decision)
         return decision
@@ -606,7 +615,9 @@ class WorkflowRun:
                 out.append(name)
         return out
 
-    def decide(self, name: str) -> Decision:
+    def decide(self, name: str, **span_attrs) -> Decision:
+        """Bind stage ``name``; ``span_attrs`` annotate the node's
+        ``decide/<node>`` span."""
         stage = self.workflow.stages[name]
         if name in self.decisions:
             raise LateBindingError(f"stage {name!r} already decided")
@@ -618,7 +629,7 @@ class WorkflowRun:
                 f"awaiting feedback from {unfed}")
         from repro.obs.audit import bound_app
         with bound_app(self.app):
-            decision = stage.node.decide(self.ctx)
+            decision = stage.node.decide(self.ctx, **span_attrs)
         self.decisions[name] = decision
         self.ctx.decisions = dict(self.ctx.decisions, **{name: decision})
         return decision

@@ -15,6 +15,14 @@ pads its input to the next power of two before hitting the jitted body, so
 32 map partitions with 32 different post-filter row counts compile a
 handful of executables (one per power-of-two class), not 32 — the
 no-per-partition-recompilation property the CI smoke benchmark asserts.
+
+Host <-> device: a function body that needs a device result on the host,
+or host numpy on the device, goes through ``device_wait`` /
+``host_copy`` / ``device_copy``. With the tracer on they open
+``sync/<site>`` (the host blocked on the device) and ``xfer/d2h`` /
+``xfer/h2d`` spans (the copy, with its ``bytes``), tagged with the
+calling function's name; with it off they are the bare copy or wait, so
+tracing adds no blocking point.
 """
 
 from __future__ import annotations
@@ -43,6 +51,77 @@ EMPTY = jnp.int32(-1)
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+# -- host <-> device -----------------------------------------------------------
+
+
+def _arrays(tree) -> list:
+    """The arrays of a column dict, a list or tuple, or one array."""
+    if isinstance(tree, dict):
+        return list(tree.values())
+    if isinstance(tree, (list, tuple)):
+        return list(tree)
+    return [tree]
+
+
+def _rebuilt(tree, arrays: list):
+    """``tree``'s shape (key order kept) holding ``arrays``."""
+    if isinstance(tree, dict):
+        return dict(zip(tree, arrays))
+    if isinstance(tree, (list, tuple)):
+        return type(tree)(arrays)
+    return arrays[0]
+
+
+def device_wait(tree, site: str):
+    """Block until every device array of ``tree`` is computed, under a
+    ``sync/<site>`` span; returns ``tree``."""
+    tr = get_tracer()
+    if not tr.enabled or not any(isinstance(x, jax.Array)
+                                 for x in _arrays(tree)):
+        return jax.block_until_ready(tree)
+    with tr.span(f"sync/{site}", "sync", func=tr.current_attr("func")):
+        return jax.block_until_ready(tree)
+
+
+def host_copy(tree, site: str):
+    """``tree`` with every array as host numpy. Traced, the wait for the
+    device results is a ``sync/<site>`` span and the copy an ``xfer/d2h``
+    span; untraced, one ``np.asarray`` per array, as blocking as before."""
+    arrays = _arrays(tree)
+    tr = get_tracer()
+    on_dev = [x for x in arrays if isinstance(x, jax.Array)] \
+        if tr.enabled else ()
+    if not on_dev:
+        return _rebuilt(tree, [np.asarray(x) for x in arrays])
+    func = tr.current_attr("func")
+    with tr.span(f"sync/{site}", "sync", func=func):
+        jax.block_until_ready(on_dev)
+    with tr.span("xfer/d2h", "xfer", func=func,
+                 bytes=sum(int(x.nbytes) for x in on_dev)):
+        return _rebuilt(tree, [np.asarray(x) for x in arrays])
+
+
+def device_copy(tree):
+    """``tree`` with its host numpy arrays put on the device in one
+    ``jax.device_put``, under an ``xfer/h2d`` span (traced, it lasts until
+    the copy has landed); device arrays pass through untouched."""
+    arrays = _arrays(tree)
+    host = [i for i, x in enumerate(arrays) if isinstance(x, np.ndarray)]
+    if not host:
+        return tree
+    tr = get_tracer()
+    if not tr.enabled:
+        moved = jax.device_put([arrays[i] for i in host])
+    else:
+        with tr.span("xfer/h2d", "xfer", func=tr.current_attr("func"),
+                     bytes=sum(int(arrays[i].nbytes) for i in host)):
+            moved = jax.block_until_ready(
+                jax.device_put([arrays[i] for i in host]))
+    for i, x in zip(host, moved):
+        arrays[i] = x
+    return _rebuilt(tree, arrays)
 
 
 # -- attention -----------------------------------------------------------------
@@ -277,16 +356,20 @@ def heavy_hitter_sketch(keys, k: int = HOT_KEYS_K,
     k = max(1, int(k))
     keys = jnp.asarray(keys, jnp.int32)
     slot_ids = partition_ids(keys, num_slots)
-    hist = np.asarray(partition_histogram(slot_ids, num_slots,
-                                          force_kernel=force_kernel))
+    hist = host_copy(partition_histogram(slot_ids, num_slots,
+                                         force_kernel=force_kernel),
+                     "sketch_hist")
     cand = np.argsort(-hist, kind="stable")[:k]
     cand = cand[hist[cand] > 0]
     if cand.size == 0:
         return ()
-    mask = np.isin(np.asarray(slot_ids), cand)
-    sub = np.asarray(keys)[mask]
-    uniq, counts = np.unique(sub, return_counts=True)
-    order = np.lexsort((uniq, -counts))[:k]
+    slots_h, keys_h = host_copy([slot_ids, keys], "sketch_keys")
+    tr = get_tracer()
+    with tr.span("host/sketch_verify", "host", func=tr.current_attr("func"),
+                 rows=n):
+        sub = keys_h[np.isin(slots_h, cand)]
+        uniq, counts = np.unique(sub, return_counts=True)
+        order = np.lexsort((uniq, -counts))[:k]
     return tuple((int(uniq[i]), int(counts[i])) for i in order)
 
 
@@ -446,6 +529,8 @@ def fused_probe_groups(probe_keys, v0, v1, build_keys, build_cat,
     with get_tracer().span("kernel/fused_probe", "kernel", rows=n,
                            build_rows=m, shape_class=n_pad,
                            path="pallas" if kernel_ok else "jit"):
+        probe_keys, v0, v1, build_keys, build_cat = device_copy(
+            [probe_keys, v0, v1, build_keys, build_cat])
         pk = jnp.asarray(probe_keys, jnp.int32)
         v0 = jnp.asarray(v0, jnp.float32)
         v1 = jnp.asarray(v1, jnp.float32)
@@ -466,7 +551,8 @@ def fused_probe_groups(probe_keys, v0, v1, build_keys, build_cat,
         else:
             grp, wgt = _fused_probe_padded(pk, v0, v1, bk, bc, bv,
                                            num_groups)
-        return np.asarray(grp)[:n], np.asarray(wgt)[:n]
+        grp, wgt = host_copy([grp, wgt], "fused_probe")
+        return grp[:n], wgt[:n]
 
 
 # -- aggregation ---------------------------------------------------------------

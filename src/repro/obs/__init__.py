@@ -8,7 +8,9 @@ buffer; the global ``DecisionAuditLog`` (``get_audit_log``) records every
 ``DecisionNode`` binding with the context snapshot it saw. On top:
 ``critical_path`` walks the span DAG to the chain bounding a query's
 makespan, and ``to_chrome_trace``/``write_chrome_trace`` emit a
-Perfetto-loadable timeline.
+Perfetto-loadable timeline. ``Tracer.annotate`` mirrors every context
+span onto an external timeline; ``repro.runtime`` points it at the JAX
+profiler, so this package never imports jax.
 """
 
 from repro.obs.audit import (

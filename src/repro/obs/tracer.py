@@ -2,8 +2,9 @@
 
 A ``Span`` is one timed region of runtime work, tagged with a *trace id*
 (the application/query name — every span of one query shares it), a
-category (``scheduler`` | ``executor`` | ``invoker`` | ``store`` |
-``kernel`` | ``wait``) and free-form attributes. Spans form a DAG:
+category (``scheduler`` | ``planner`` | ``executor`` | ``invoker`` |
+``store`` | ``xfer`` | ``sync`` | ``kernel`` | ``wait``) and free-form
+attributes. Spans form a DAG:
 
 * within a thread, ``tracer.span(...)`` nests — the innermost open span is
   the default parent (a store read inside a function body parents to the
@@ -22,6 +23,14 @@ bound), and with ``enabled=False`` every entry point is an early-out no-op
 under 5%). Timestamps are ``time.perf_counter()`` — the same clock as
 ``InvocationRecord`` — so spans and metrics line up.
 
+Profiler clock: every context span (``tracer.span``) also enters
+``Tracer.annotate``, when one is installed, with the label
+``repro:<cat>:<name>``. ``repro.runtime`` installs the JAX profiler's
+``TraceAnnotation`` there, so under ``jax.profiler.trace`` the program's
+spans sit on the trace's host plane, on its clock, beside the device's
+operations. This module itself stays free of jax. A disabled tracer never
+enters the hook.
+
 ``count(track, value)`` records counter samples (e.g. live store bytes per
 app, slots in use per node) that the Chrome-trace exporter renders as
 counter tracks; ``delta=True`` samples are integrated at export time.
@@ -38,6 +47,7 @@ from dataclasses import dataclass, field
 
 
 _CURRENT = object()     # sentinel: parent = the calling thread's open span
+ANNOTATION_PREFIX = "repro:"   # marks the program's spans on a profiler trace
 
 
 @dataclass
@@ -47,7 +57,7 @@ class Span:
     span_id: int
     trace: str                     # trace id: the app/query name
     name: str                      # e.g. "stage/join", "query/scan_fact/3"
-    cat: str                       # scheduler|executor|invoker|store|kernel|wait
+    cat: str                       # scheduler|planner|executor|invoker|...
     start: float                   # perf_counter seconds
     end: float = 0.0
     parent_id: int | None = None
@@ -61,6 +71,12 @@ class Span:
 
 class Tracer:
     """Bounded, thread-safe collector of spans and counter samples."""
+
+    # ``(label, attrs) -> context manager`` entered around every context
+    # span of an enabled tracer: the mirror onto an external timeline
+    # (``repro.runtime`` installs the JAX profiler's ``TraceAnnotation``).
+    # ``None`` mirrors nothing.
+    annotate = None
 
     def __init__(self, capacity: int = 65536, enabled: bool = True):
         self._lock = threading.Lock()
@@ -86,6 +102,14 @@ class Tracer:
         """The calling thread's innermost open span, if any."""
         st = getattr(self._tls, "stack", None)
         return st[-1] if st else None
+
+    def current_attr(self, key: str):
+        """``key`` of the innermost open span (this thread) that has it —
+        e.g. the ``func`` of the invocation whose body is running."""
+        for sp in reversed(getattr(self._tls, "stack", None) or ()):
+            if key in sp.attrs:
+                return sp.attrs[key]
+        return None
 
     # -- span lifecycle -------------------------------------------------------
 
@@ -120,19 +144,34 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, cat: str, trace: str | None = None,
-             node: int | None = None, parent=_CURRENT, **attrs):
-        """Context-managed span, pushed on the thread stack so spans opened
-        inside (same thread) parent to it automatically."""
+             node: int | None = None, parent=_CURRENT, nest: bool = True,
+             **attrs):
+        """Context-managed span, mirrored onto the profiler's timeline.
+
+        With ``nest`` (the default) it is pushed on the thread stack, so
+        spans opened inside (same thread) parent to it automatically.
+        ``nest=False`` times a region without owning what opens inside it
+        (an invocation's attempt, a store call): those spans keep the
+        enclosing span as their parent."""
         if not self.enabled:
             yield None
             return
         sp = self.start(name, cat, trace=trace, node=node, parent=parent,
                         **attrs)
-        self._stack().append(sp)
+        mirror = self.annotate
+        ann = mirror(f"{ANNOTATION_PREFIX}{cat}:{name}", sp.attrs) \
+            if mirror is not None else None
+        if nest:
+            self._stack().append(sp)
+        if ann is not None:
+            ann.__enter__()
         try:
             yield sp
         finally:
-            self._stack().pop()
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            if nest:
+                self._stack().pop()
             self.end(sp)
 
     @contextmanager

@@ -62,7 +62,15 @@ class StagePlanner:
     recorded, ephemeral inputs not yet reclaimed) and returns further
     stages to schedule — typically by binding the next late-bound decisions
     of a ``WorkflowRun``. Return an empty list when nothing new unlocks.
+    ``app`` names the query it plans for: ``DAGExecutor.run`` given no
+    stages asks ``initial_stages`` itself, under the query's root span.
+
+    The executor times every call into the planner as a ``plan/<stage>``
+    span (``plan/initial`` for the first), parented to the query's root;
+    the decisions bound inside nest under it as ``decide/<node>``.
     """
+
+    app: str | None = None
 
     def initial_stages(self) -> list[RuntimeStage]:  # pragma: no cover
         return []
@@ -115,9 +123,12 @@ class DAGExecutor:
             self._ok.add(rec.name)
             self._ok_cond.notify_all()
 
-    def run(self, stages: Sequence[RuntimeStage],
+    def run(self, stages: Sequence[RuntimeStage] | None,
             pc: PrivateController | None = None,
             planner: StagePlanner | None = None) -> dict[str, StageMetrics]:
+        """Run the DAG to completion. ``stages=None`` takes the first
+        stages from ``planner.initial_stages()``, called once the query's
+        root span is open."""
         known: dict[str, RuntimeStage] = {}
         pending: dict[str, RuntimeStage] = {}   # insertion-ordered
         completed: set[str] = set()
@@ -136,11 +147,14 @@ class DAGExecutor:
                     raise ValueError(
                         f"stage {st.name!r} depends on unknown {missing}")
 
-        admit(stages)
-        if not known:
-            return {}
-        app = next(st.invocations[0].app for st in known.values()
-                   if st.invocations)
+        if stages is None:
+            app = planner.app
+        else:
+            admit(stages)
+            if not known:
+                return {}
+            app = next(st.invocations[0].app for st in known.values()
+                       if st.invocations)
         invoker = self.runtime.invoker
         metrics = self.runtime.metrics
         # root the query's span tree: when no scheduler anchored a
@@ -153,17 +167,31 @@ class DAGExecutor:
                                 parent=None)
             tr.anchor(("query", app), own_root)
 
+        def plan(stage: str, call) -> None:
+            """Admit the stages ``call`` returns, timed as ``plan/<stage>``."""
+            with tr.span(f"plan/{stage}", "planner", trace=app,
+                         parent=tr.anchored(("query", app))) as psp:
+                batch = list(call() or ())
+                if psp is not None:
+                    psp.attrs["admitted"] = len(batch)
+            admit(batch)
+
+        def replan(st: RuntimeStage) -> list[RuntimeStage]:
+            if pc is not None:
+                pc.record_profile(
+                    **metrics.profile_feedback(app, stage=st.name))
+            if planner is None:
+                return []
+            return planner.on_stage_complete(st.name, self.runtime, pc)
+
         def dep_invs(st: RuntimeStage) -> tuple[str, ...]:
             return tuple(inv.name for d in st.deps
                          for inv in known[d].invocations)
 
         def finish(st: RuntimeStage) -> None:
             completed.add(st.name)
-            if pc is not None:
-                pc.record_profile(
-                    **metrics.profile_feedback(app, stage=st.name))
-            if planner is not None:
-                admit(planner.on_stage_complete(st.name, self.runtime, pc))
+            if pc is not None or planner is not None:
+                plan(st.name, lambda: replan(st))
             for src in st.ephemeral_inputs:
                 # under a quota the stage is sealed (lazily evicted when the
                 # app needs headroom); otherwise dropped immediately
@@ -174,6 +202,10 @@ class DAGExecutor:
             metrics.subscribe(self._on_record)
             invoker.honor_plan = True
         try:
+            if stages is None:
+                plan("initial", planner.initial_stages)
+                if not known:
+                    return {}
             if self.barrier or not getattr(invoker, "parallel", False):
                 self._run_serial(pending, completed, invoker, dep_invs,
                                  finish)
@@ -476,7 +508,7 @@ class Runtime:
         """
         return self.store.ingest(app, stage, partitions, tier=tier)
 
-    def execute(self, stages: Sequence[RuntimeStage],
+    def execute(self, stages: Sequence[RuntimeStage] | None,
                 pc: PrivateController | None = None,
                 planner: StagePlanner | None = None,
                 barrier: bool = False, max_recoveries: int = 8,

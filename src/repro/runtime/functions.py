@@ -47,6 +47,7 @@ import numpy as np
 from repro.analytics import operators as ops
 from repro.analytics.table import Table
 from repro.kernels import ops as kops
+from repro.obs.tracer import get_tracer
 
 FUNCTIONS: Dict[str, Callable] = {}
 
@@ -124,8 +125,8 @@ def shuffle_write(ctx) -> None:
     # every bucket slice is then a zero-copy numpy view, and readers
     # concatenate views with a memcpy — device programs are reserved for
     # the kernels, not for per-(shape, range) slice/concat plumbing
-    permuted = Table({k: np.asarray(v) for k, v in t.take(order).columns.items()})
-    bounds = np.asarray(offsets)
+    permuted = Table(kops.host_copy(t.take(order).columns, "shuffle_write"))
+    bounds = kops.host_copy(offsets, "shuffle_offsets")
     # skew detection rides the grouping we already paid for: the offset
     # diffs ARE the per-bucket row histogram, and the heavy-hitter sketch
     # is one fixed-shape hash-slot histogram (Pallas on TPU) plus an exact
@@ -154,11 +155,11 @@ def shuffle_write_loop(ctx) -> None:
     if t is None or t.num_rows == 0:
         return
     nb = int(p["num_buckets"])
-    pids = np.asarray(ops.partition_ids(t["key"], nb))
+    pids = kops.host_copy(ops.partition_ids(t["key"], nb), "shuffle_pids")
     for r in range(nb):
         idx = np.nonzero(pids == r)[0]
         if idx.size:
-            ctx.put(p["dst"], r, t.take(jnp.asarray(idx)))
+            ctx.put(p["dst"], r, t.take(kops.device_copy(idx)))
 
 
 @register("broadcast_write")
@@ -232,12 +233,13 @@ def _mitigation_view(fact, p):
         fact = fact.slice(lo, hi).materialize()
     drop = p.get("drop_keys")
     if drop:
-        keep = ~np.isin(np.asarray(fact["key"]), list(drop))
-        fact = fact.mask(jnp.asarray(keep))
+        keep = ~np.isin(kops.host_copy(fact["key"], "skew_keys"), list(drop))
+        fact = fact.mask(kops.device_copy(keep))
     keep_keys = p.get("keep_keys")
     if keep_keys:
-        keep = np.isin(np.asarray(fact["key"]), list(keep_keys))
-        fact = fact.mask(jnp.asarray(keep))
+        keep = np.isin(kops.host_copy(fact["key"], "skew_keys"),
+                       list(keep_keys))
+        fact = fact.mask(kops.device_copy(keep))
     return fact
 
 
@@ -271,13 +273,19 @@ def _join_partition(ctx, method: str) -> None:
             fact["key"], fact["v0"], fact["v1"], dim["key"], dim["cat"],
             int(p["num_groups"]))
         ctx.put(p["dst"], p["partition"],
-                Table({"group": jnp.asarray(group),
-                       "weight": jnp.asarray(weight)}))
+                Table(kops.device_copy({"group": group, "weight": weight})))
         return
-    joined = ops.join(fact, dim, method=method)
-    found = joined["found"]
-    weight = jnp.where(found, joined["v0"] * joined["v1"], 0.0)
-    group = joined["cat"].astype(jnp.int32) % int(p["num_groups"])
+    # the join's dispatches, timed as one kernel span: with the device's
+    # queue full, a dispatch itself waits for earlier work to retire
+    with get_tracer().span("kernel/join", "kernel", method=method,
+                           rows=fact.num_rows, build_rows=dim.num_rows,
+                           path="jit"):
+        joined = ops.join(fact, dim, method=method)
+        found = joined["found"]
+        # a shuffled (host) fact side multiplies on the host, then goes up
+        weight = jnp.where(found,
+                           kops.device_copy(joined["v0"] * joined["v1"]), 0.0)
+        group = joined["cat"].astype(jnp.int32) % int(p["num_groups"])
     ctx.put(p["dst"], p["partition"],
             Table({"group": group, "weight": weight}))
 
@@ -317,9 +325,9 @@ def hot_filter_write(ctx) -> None:
         t = ctx.get(p["src"], part)
         if t is None or t.num_rows == 0:
             continue
-        keep = np.isin(np.asarray(t["key"]), keys)
+        keep = np.isin(kops.host_copy(t["key"], "hot_keys"), keys)
         if keep.any():
-            got.append(t.mask(jnp.asarray(keep)))
+            got.append(t.mask(kops.device_copy(keep)))
     if got:
         ctx.put(p["dst"], 0, Table.concat_all(got))
 
@@ -354,6 +362,8 @@ def final_aggregate(ctx) -> None:
     vecs = [t["sum"] for t in (ctx.get(p["src"], part)
                                for part in ctx.partitions(p["src"]))
             if t is not None and t.num_rows]
-    total = (np.stack([np.asarray(v, dtype=np.float64) for v in vecs])
+    total = (np.stack([v.astype(np.float64)
+                       for v in kops.host_copy(vecs, "final_partials")])
              .sum(axis=0) if vecs else np.zeros(g, dtype=np.float64))
-    ctx.put(p["dst"], 0, Table({"sum": jnp.asarray(total, jnp.float32)}))
+    ctx.put(p["dst"], 0,
+            Table({"sum": kops.device_copy(total.astype(np.float32))}))

@@ -224,14 +224,12 @@ class FnContext:
         # per-stage metrics and stage overlap alike). This wait is charged
         # to compute, not store time — it is the invocation's own pending
         # device work draining.
-        try:
-            import jax
-            cols = getattr(table, "parent_columns", None)
-            if cols is None:
-                cols = getattr(table, "columns", None)
-            jax.block_until_ready(cols)
-        except ImportError:  # pragma: no cover - jax is a hard dep elsewhere
-            pass
+        # Traced, the wait is a ``sync/put`` span.
+        from repro.kernels.ops import device_wait
+        cols = getattr(table, "parent_columns", None)
+        if cols is None:
+            cols = getattr(table, "columns", None)
+        device_wait(cols, "put")
 
     def put(self, stage: str, partition: int, table) -> None:
         self._force(table)
@@ -458,58 +456,63 @@ class Invoker:
             # of the invocation's observed duration, which is what the
             # speculation policy and the tail benchmarks reason about
             t0 = time.perf_counter()
-            try:
+            # the attempt times the claim's body without owning the spans
+            # opened in it: store and body spans stay the invocation's
+            # children, which is what the critical path splits
+            with tr.span(f"attempt/{attempt}", "invoker", trace=inv.app,
+                         node=inv.node, parent=sp, nest=False,
+                         func=inv.func, kind="attempt") as asp:
                 try:
-                    if self.intercept is not None:
-                        self.intercept(inv, attempt)
-                    if self.injector is not None:
-                        self.injector.before_body(inv, attempt)
-                    ctx = self._invoke_body(fn, inv, attempt)
-                    if self.injector is not None:
-                        self.injector.after_body(inv, attempt)
-                except InjectedCrashError as e:
-                    # an injected function crash: release the slot, record
-                    # the death, and retry on the next attempt (stateless
-                    # functions + writer-label overwrite make a
-                    # crash-after-write retry safe — it replaces, never
-                    # duplicates)
-                    crashed = e
-                    self.gc.finish(claim)
-                    tr.count(f"slots/node{inv.node}", -1, delta=True)
-                except BaseException:
-                    # any other failure while the claim is live — the
-                    # registered function itself raising, the intercept
-                    # hook, a StageLostError from the store — must release
-                    # the slot, not leak it (a leaked slot deadlocks
-                    # FairShareGate accounting)
-                    self.gc.finish(claim)
-                    tr.count(f"slots/node{inv.node}", -1, delta=True)
-                    self.metrics.record(InvocationRecord(
-                        inv.name, inv.app, inv.stage, inv.func, inv.node,
-                        attempt, "error", t0, time.perf_counter(), deps=deps,
-                        priority=inv.priority))
-                    if sp is not None:
-                        sp.attrs.update(status="error", attempts=attempt + 1)
-                        tr.record(f"attempt/{attempt}", "invoker", t0,
-                                  trace=inv.app, node=inv.node, parent=sp,
-                                  kind="attempt", status="error")
-                    raise
-                if crashed is None:
-                    t1 = time.perf_counter()
-                    committed = self.gc.finish(claim)
-                    tr.count(f"slots/node{inv.node}", -1, delta=True)
-            finally:
-                if self.gate is not None:
-                    self.gate.release(inv)
+                    try:
+                        if self.intercept is not None:
+                            self.intercept(inv, attempt)
+                        if self.injector is not None:
+                            self.injector.before_body(inv, attempt)
+                        ctx = self._invoke_body(fn, inv, attempt)
+                        if self.injector is not None:
+                            self.injector.after_body(inv, attempt)
+                    except InjectedCrashError as e:
+                        # an injected function crash: release the slot,
+                        # record the death, and retry on the next attempt
+                        # (stateless functions + writer-label overwrite
+                        # make a crash-after-write retry safe — it
+                        # replaces, never duplicates)
+                        crashed = e
+                        self.gc.finish(claim)
+                        tr.count(f"slots/node{inv.node}", -1, delta=True)
+                    except BaseException:
+                        # any other failure while the claim is live — the
+                        # registered function itself raising, the
+                        # intercept hook, a StageLostError from the store —
+                        # must release the slot, not leak it (a leaked
+                        # slot deadlocks FairShareGate accounting)
+                        self.gc.finish(claim)
+                        tr.count(f"slots/node{inv.node}", -1, delta=True)
+                        self.metrics.record(InvocationRecord(
+                            inv.name, inv.app, inv.stage, inv.func,
+                            inv.node, attempt, "error", t0,
+                            time.perf_counter(), deps=deps,
+                            priority=inv.priority))
+                        if sp is not None:
+                            sp.attrs.update(status="error",
+                                            attempts=attempt + 1)
+                            asp.attrs["status"] = "error"
+                        raise
+                    if crashed is None:
+                        t1 = time.perf_counter()
+                        committed = self.gc.finish(claim)
+                        tr.count(f"slots/node{inv.node}", -1, delta=True)
+                finally:
+                    if self.gate is not None:
+                        self.gate.release(inv)
+                if asp is not None:
+                    asp.attrs["status"] = "crashed" if crashed is not None \
+                        else "ok" if committed else "preempted"
             if crashed is not None:
                 self.metrics.record(InvocationRecord(
                     inv.name, inv.app, inv.stage, inv.func, inv.node,
                     attempt, "crashed", t0, time.perf_counter(), deps=deps,
                     priority=inv.priority))
-                if sp is not None:
-                    tr.record(f"attempt/{attempt}", "invoker", t0,
-                              trace=inv.app, node=inv.node, parent=sp,
-                              kind="attempt", status="crashed")
                 continue
             status = "ok" if committed else "preempted"
             self.metrics.record(InvocationRecord(
@@ -523,9 +526,6 @@ class Invoker:
                 stats=dict(ctx.stats)))
             if sp is not None:
                 sp.attrs.update(status=status, attempts=attempt + 1)
-                tr.record(f"attempt/{attempt}", "invoker", t0, end=t1,
-                          trace=inv.app, node=inv.node, parent=sp,
-                          kind="attempt", status=status)
             if committed:
                 return
         self.metrics.record(InvocationRecord(

@@ -348,7 +348,7 @@ class QueryScheduler:
                 self.runtime, job.fact, job.dim, strategy, app=job.app,
                 priority=job.priority, num_groups=job.num_groups,
                 workflow=job.workflow)
-            self.runtime.execute(plan.initial_stages(), pc=pc, planner=plan,
+            self.runtime.execute(None, pc=pc, planner=plan,
                                  recovery=self.recovery,
                                  max_recoveries=self.max_recoveries)
             res.sums = self.runtime.result(job.app)

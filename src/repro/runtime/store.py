@@ -435,23 +435,23 @@ class ShuffleStore:
         ``tier`` names a cold backend to seed directly (bypasses the hot
         quota — the data never occupies memory). Returns the bytes written.
         """
-        tr = get_tracer()
-        t0 = time.perf_counter() if tr.enabled else 0.0
         nbytes, rows = int(table.nbytes), int(table.num_rows)
-        with self._cond:
-            pending = self._put_locked(app, stage, partition, table, node,
-                                       writer, nbytes, rows, tier=tier)
-        if pending:
-            time.sleep(pending)
-        # the emulated disaggregated transfer is charged only AFTER quota
-        # admission succeeds: a write rejected by the quota (or blocked on
-        # eviction) must not pay the transfer once per failed attempt, which
-        # would inflate store_seconds and the critical-path store split
-        if self.disaggregated and self.net_bw and writer != "seed":
-            time.sleep(nbytes / self.net_bw)
-        if tr.enabled:
-            tr.record(f"put/{stage}", "store", t0, trace=app, node=node,
-                      partition=partition, bytes=nbytes)
+        with get_tracer().span(f"put/{stage}", "store", trace=app, node=node,
+                               nest=False, partition=partition,
+                               bytes=nbytes):
+            with self._cond:
+                pending = self._put_locked(app, stage, partition, table,
+                                           node, writer, nbytes, rows,
+                                           tier=tier)
+            if pending:
+                time.sleep(pending)
+            # the emulated disaggregated transfer is charged only AFTER
+            # quota admission succeeds: a write rejected by the quota (or
+            # blocked on eviction) must not pay the transfer once per
+            # failed attempt, which would inflate store_seconds and the
+            # critical-path store split
+            if self.disaggregated and self.net_bw and writer != "seed":
+                time.sleep(nbytes / self.net_bw)
         return nbytes
 
     def put_many(self, app: str, stage: str, tables: Mapping[int, object],
@@ -469,26 +469,27 @@ class ShuffleStore:
         is one sleep for the total bytes (one flow, not P serialized ones).
         Returns total bytes written.
         """
-        tr = get_tracer()
-        t0 = time.perf_counter() if tr.enabled else 0.0
         sized = [(int(p), t, int(t.nbytes), int(t.num_rows))
                  for p, t in sorted(tables.items())]
         total = sum(nb for _, _, nb, _ in sized)
-        with self._cond:
-            pending = self._admit(app, stage,
-                                  [(p, writer, nb) for p, _, nb, _ in sized])
-            for partition, table, nbytes, rows in sized:
-                pending += self._insert_locked(app, stage, partition, table,
-                                               node, writer, nbytes, rows)
-        if pending:
-            time.sleep(pending)
-        # transfer charged after admission (see ``put``): a quota rejection
-        # mid-batch pays nothing for the flow it never completed
-        if self.disaggregated and self.net_bw and writer != "seed" and total:
-            time.sleep(total / self.net_bw)
-        if tr.enabled:
-            tr.record(f"put_many/{stage}", "store", t0, trace=app, node=node,
-                      partitions=len(sized), bytes=total)
+        with get_tracer().span(f"put_many/{stage}", "store", trace=app,
+                               node=node, nest=False, partitions=len(sized),
+                               bytes=total):
+            with self._cond:
+                pending = self._admit(
+                    app, stage, [(p, writer, nb) for p, _, nb, _ in sized])
+                for partition, table, nbytes, rows in sized:
+                    pending += self._insert_locked(app, stage, partition,
+                                                   table, node, writer,
+                                                   nbytes, rows)
+            if pending:
+                time.sleep(pending)
+            # transfer charged after admission (see ``put``): a quota
+            # rejection mid-batch pays nothing for the flow it never
+            # completed
+            if self.disaggregated and self.net_bw and writer != "seed" \
+                    and total:
+                time.sleep(total / self.net_bw)
         return total
 
     def ingest(self, app: str, stage: str, partitions,
@@ -540,17 +541,16 @@ class ShuffleStore:
         if not tr.enabled:
             return self._get_impl(app, stage, partition, node, account,
                                   writers)
-        t0 = time.perf_counter()
-        try:
-            t = self._get_impl(app, stage, partition, node, account, writers)
-        except StageLostError:
-            tr.record(f"get/{stage}", "store", t0, trace=app, node=node,
-                      partition=partition, status="lost")
-            raise
-        tr.record(f"get/{stage}", "store", t0, trace=app, node=node,
-                  partition=partition,
-                  bytes=int(t.nbytes) if t is not None else 0,
-                  status="ok" if t is not None else "miss")
+        with tr.span(f"get/{stage}", "store", trace=app, node=node,
+                     nest=False, partition=partition) as sp:
+            try:
+                t = self._get_impl(app, stage, partition, node, account,
+                                   writers)
+            except StageLostError:
+                sp.attrs["status"] = "lost"
+                raise
+            sp.attrs.update(bytes=int(t.nbytes) if t is not None else 0,
+                            status="ok" if t is not None else "miss")
         return t
 
     def get_async(self, app: str, stage: str, partition: int, node: int,

@@ -361,12 +361,235 @@ def test_no_orphan_store_spans_in_pipelined_run():
 # -- overhead / disabled end-to-end ----------------------------------------------
 
 
-def test_query_runs_clean_with_tracer_disabled():
+def test_query_runs_clean_with_tracer_disabled(monkeypatch):
+    entered = []
+    monkeypatch.setattr(Tracer, "annotate",
+                        staticmethod(lambda label, attrs: entered.append(
+                            label)))
     prev = set_tracer(Tracer(enabled=False))
     try:
         fd, dd, ref = make_dist_tables(rows=2048, dim_rows=256, seed=8)
         got, _ = execute_query_runtime(fd, dd, QueryStrategy("static_merge"))
         np.testing.assert_allclose(got, ref, atol=1e-3)
         assert get_tracer().spans() == []
+        # the profiler mirror is never entered by a disabled tracer
+        assert entered == []
     finally:
         set_tracer(prev)
+
+
+# -- planner, host<->device and profiler-clock spans -----------------------------
+
+
+def test_obs_imports_no_jax():
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    code = "import sys, repro.obs; assert 'jax' not in sys.modules"
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
+                   env={**os.environ, "PYTHONPATH": src})
+
+
+def test_unnested_span_keeps_the_enclosing_parent():
+    tr = Tracer()
+    with tr.span("inv", "invoker", trace="t") as inv:
+        with tr.span("attempt/0", "invoker", nest=False) as att:
+            with tr.span("get/x", "store") as get:
+                pass
+    assert att.parent_id == inv.span_id
+    assert get.parent_id == inv.span_id        # not the attempt's
+    assert att.start <= get.start and get.end <= att.end
+
+
+def test_mirror_sees_every_context_span_with_its_label(monkeypatch):
+    seen = []
+
+    class Mirror:
+        def __init__(self, label, attrs):
+            self.label, self.attrs = label, attrs
+
+        def __enter__(self):
+            seen.append(("enter", self.label, self.attrs.get("func")))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.label, None))
+
+    monkeypatch.setattr(Tracer, "annotate", staticmethod(Mirror))
+    tr = Tracer()
+    with tr.span("a/s/0", "invoker", trace="t", func="f"):
+        with tr.span("xfer/d2h", "xfer", nest=False):
+            pass
+    tr.record("slot_wait", "wait", 0.0)        # retroactive: not mirrored
+    assert seen == [("enter", "repro:invoker:a/s/0", "f"),
+                    ("enter", "repro:xfer:xfer/d2h", None),
+                    ("exit", "repro:xfer:xfer/d2h", None),
+                    ("exit", "repro:invoker:a/s/0", None)]
+
+
+def test_transfer_helpers_span_waits_and_copies():
+    from repro.kernels import ops as kops
+
+    tr = Tracer()
+    prev = set_tracer(tr)
+    try:
+        dev = {"k": jnp.arange(8, dtype=jnp.int32), "v": jnp.ones(8)}
+        with tr.span("q/s/0", "invoker", trace="q", func="fn",
+                     kind="invocation") as inv:
+            host = kops.host_copy(dev, "site")
+            back = kops.device_copy(host)
+            same = kops.device_copy(dev)           # already on the device
+            kops.device_wait(back, "put")
+            kops.host_copy(host, "noop")           # already on the host
+    finally:
+        set_tracer(prev)
+    assert list(host) == ["k", "v"]
+    assert all(isinstance(v, np.ndarray) for v in host.values())
+    assert same is dev
+    np.testing.assert_array_equal(back["k"], dev["k"])
+    spans = {s.name: s for s in tr.spans("q") if s.name != "q/s/0"}
+    assert sorted(spans) == ["sync/put", "sync/site", "xfer/d2h", "xfer/h2d"]
+    for s in spans.values():
+        assert s.parent_id == inv.span_id and s.attrs["func"] == "fn"
+    assert spans["xfer/d2h"].attrs["bytes"] == 8 * 4 + 8 * 4
+    assert spans["xfer/h2d"].attrs["bytes"] == 8 * 4 + 8 * 4
+    assert spans["sync/site"].end <= spans["xfer/d2h"].start
+
+
+def test_planner_spans_nest_decisions_under_the_query_root():
+    fd, dd, ref = make_dist_tables(rows=2048, dim_rows=256, seed=5)
+    wf = build_query_workflow(QueryStrategy("static_merge"))
+    got, _ = execute_query_runtime(fd, dd, QueryStrategy("static_merge"),
+                                   workflow=wf)
+    np.testing.assert_allclose(got, ref, atol=1e-3)
+    spans = get_tracer().spans("query")
+    by_id = {s.span_id: s for s in spans}
+    root = next(s for s in spans if s.name == "query/query")
+    plans = [s for s in spans if s.name.startswith("plan/")]
+    assert {s.cat for s in plans} == {"planner"}
+    assert "plan/initial" in {s.name for s in plans}
+    assert all(s.parent_id == root.span_id for s in plans)
+    # every stage the planner materialised is counted on its span
+    stages = [s for s in spans if s.name.startswith("stage/")]
+    assert sum(s.attrs["admitted"] for s in plans) == len(stages)
+    # each bound decision is one decide/<node> span inside a plan span,
+    # carrying the value the audit log records
+    decides = [s for s in spans if s.name.startswith("decide/")]
+    assert [(s.name[len("decide/"):], s.attrs["value"])
+            for s in sorted(decides, key=lambda s: s.start)] == \
+        [(n, d.func) for n, d in wf.last_run.sequence]
+    assert all(by_id[s.parent_id].name.startswith("plan/") for s in decides)
+    # the bodies' host<->device traffic is spanned under its invocation
+    # (directly, or inside the join's dispatch span), tagged with its func
+    body = [s for s in spans if s.cat in ("xfer", "sync", "host")]
+    assert {s.name for s in body} >= {
+        "xfer/d2h", "xfer/h2d", "sync/put", "sync/mask_rows",
+        "sync/shuffle_write", "sync/join_idx", "host/sketch_verify"}
+    for s in body:
+        inv = by_id[s.parent_id]
+        while inv.attrs.get("kind") != "invocation":
+            assert inv.cat == "kernel", (s.name, inv.name)
+            inv = by_id[inv.parent_id]
+        assert s.attrs["func"] == inv.attrs["func"]
+
+
+def _with_body_children(spans, base_id):
+    """The same DAG with xfer/sync children under each invocation."""
+    out, sid = list(spans), base_id
+    for s in spans:
+        if s.attrs.get("kind") != "invocation":
+            continue
+        mid = (s.start + s.end) / 2
+        out.append(Span(sid, "app", "sync/put", "sync", s.start, end=mid,
+                        parent_id=s.span_id))
+        out.append(Span(sid + 1, "app", "xfer/d2h", "xfer", mid, end=s.end,
+                        parent_id=s.span_id))
+        sid += 2
+    return out
+
+
+@pytest.mark.parametrize("dag", ["exact", "store_bound", "overlapping"])
+def test_body_spans_leave_the_critical_path_unchanged(dag):
+    spans = {
+        "exact": [_stage(1, "A", (), 0.0, 10.0),
+                  _stage(2, "B", ("A",), 10.0, 20.0),
+                  _inv(3, "A", 0.0, 10.0), _inv(5, "B", 12.0, 20.0, node=1),
+                  Span(6, "app", "get/A", "store", 13.0, end=16.0,
+                       parent_id=5)],
+        "store_bound": [
+            _stage(1, "A", (), 0.0, 20.0),
+            Span(2, "app", "batch/A@0", "invoker", 0.0, end=20.0, node=0,
+                 attrs={"kind": "batch", "stage": "A"}),
+            Span(3, "app", "slot_wait", "wait", 0.0, end=2.0, parent_id=2),
+            Span(4, "app", "app/A/0", "invoker", 2.0, end=20.0, node=0,
+                 parent_id=2, attrs={"kind": "invocation", "stage": "A"}),
+            Span(5, "app", "put/out", "store", 5.0, end=17.0, parent_id=4)],
+        "overlapping": [_stage(1, "A", (), 0.0, 12.0),
+                        _stage(2, "B", ("A",), 4.0, 14.0),
+                        _inv(3, "A", 0.0, 10.0),
+                        _inv(5, "B", 4.0, 14.0, node=1),
+                        Span(6, "app", "get/A", "store", 5.0, end=10.0,
+                             parent_id=5)],
+    }[dag]
+    plain = critical_path(spans, app="app")
+    traced = critical_path(_with_body_children(spans, 100), app="app")
+    assert traced.breakdown == plain.breakdown
+    assert [s.to_dict() for s in traced.steps] == \
+        [s.to_dict() for s in plain.steps]
+
+
+def test_profiler_annotations_mirror_the_program_spans(tmp_path):
+    """Under ``jax.profiler.trace`` the program's context spans are host
+    annotations of the ``.xplane.pb``, named ``repro:<cat>:<name>``, on
+    the trace's clock: tied by one marker, as the benchmark ties its
+    window, each agrees with its ``perf_counter`` span within 1 ms."""
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    fd, dd, ref = make_dist_tables(rows=2048, dim_rows=256, seed=9)
+    execute_query_runtime(fd, dd, QueryStrategy("static_merge"))  # compile
+    get_tracer().clear()
+    jax.profiler.start_trace(str(tmp_path))
+    t_tie = time.perf_counter()
+    with jax.profiler.TraceAnnotation("test/tie"):
+        pass
+    got, _ = execute_query_runtime(fd, dd, QueryStrategy("static_merge"))
+    jax.profiler.stop_trace()
+    np.testing.assert_allclose(got, ref, atol=1e-3)
+
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    ann, tie_ns = {}, None
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name == "test/tie":
+                    tie_ns = e.start_ns
+                elif e.name.startswith("repro:"):
+                    ann.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    assert tie_ns is not None
+
+    def mirrored(s):
+        return s.cat in ("planner", "xfer", "sync") or \
+            s.name.split("/")[0] in ("get", "put", "put_many") or \
+            s.attrs.get("kind") == "invocation"
+
+    want: dict = {}
+    for s in get_tracer().spans():
+        if mirrored(s):
+            want.setdefault(f"repro:{s.cat}:{s.name}", []).append(s)
+    cats = {label.split(":")[1] for label in want}
+    assert {"planner", "xfer", "sync", "store", "invoker"} <= cats
+    assert any(label.startswith("repro:planner:decide/") for label in want)
+    for label, spans in want.items():
+        got = sorted(ann.get(label, ()))
+        assert len(got) == len(spans), label
+        for s, (lo, hi) in zip(sorted(spans, key=lambda s: s.start), got):
+            assert abs((lo - tie_ns) * 1e-9 - (s.start - t_tie)) < 1e-3
+            assert abs((hi - tie_ns) * 1e-9 - (s.end - t_tie)) < 1e-3

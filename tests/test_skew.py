@@ -287,12 +287,10 @@ def test_mitigated_plans_on_process_backend(small_skewed_tables, force):
 def test_observed_feedback_reaches_profile_and_tracer(skewed_tables):
     from repro.obs.tracer import Tracer, set_tracer
 
-    prev = set_tracer(Tracer())
+    tracer = Tracer()
+    prev = set_tracer(tracer)
     try:
         _, run = _run(skewed_tables)
-        tracks = {t for _, t, _, _ in
-                  __import__("repro.obs.tracer",
-                             fromlist=["get_tracer"]).get_tracer().counters()}
     finally:
         set_tracer(prev)
     rows = run.ctx.profile["skew.partition_rows"]
@@ -300,9 +298,13 @@ def test_observed_feedback_reaches_profile_and_tracer(skewed_tables):
     hot = run.ctx.profile["skew.hot_keys"]
     assert len(rows) == 8 and len(nbytes) == 8
     assert sum(rows) > 0 and hot and hot[0][1] >= hot[-1][1]
-    assert {"skew/query/max_partition_bytes",
-            "skew/query/mean_partition_bytes",
-            "skew/query/hot_keys"} <= tracks
+    # the partition balance the skew node decided on rides its span
+    (skew,) = [s for s in tracer.spans("query") if s.name == "decide/skew"]
+    assert skew.cat == "planner"
+    assert skew.attrs["max_partition_bytes"] == max(nbytes)
+    assert skew.attrs["mean_partition_bytes"] == int(sum(nbytes) / 8)
+    assert skew.attrs["hot_keys"] == len(hot)
+    assert skew.attrs["value"] == run.decisions["skew"].func
 
 
 # -- cross-plane parity: the sim materializes the same skew decision ---------------
