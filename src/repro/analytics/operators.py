@@ -31,6 +31,8 @@ from repro.analytics.table import Table
 from repro.kernels.ops import (  # noqa: F401  (re-exported primitives)
     EMPTY,
     HASH_MULT,
+    MAX_PROBES,
+    HashTable,
     build_hash_table,
     device_copy,
     grouping_indices,
@@ -41,6 +43,7 @@ from repro.kernels.ops import (  # noqa: F401  (re-exported primitives)
     segment_sum,
     sort_merge_join_indices,
 )
+from repro.obs.tracer import get_tracer
 
 
 def join(probe: Table, build: Table, key: str = "key",
@@ -50,8 +53,9 @@ def join(probe: Table, build: Table, key: str = "key",
     dispatch per side (build + probe for hash, sort + merge for merge)."""
     pk, bk = device_copy([probe[key], build[key]])
     if method == "hash":
-        slots = build_hash_table(bk)
-        idx, found = hash_join_indices(pk, bk, slots)
+        table = build_hash_table(bk)
+        idx, found = hash_join_indices(pk, bk, table)
+        _note_probe_rounds(table)
     elif method == "merge":
         idx, found = sort_merge_join_indices(pk, bk)
     else:
@@ -74,6 +78,17 @@ def join(probe: Table, build: Table, key: str = "key",
             found if col.ndim == 1 else found[:, None], got, 0)
     cols["found"] = found
     return Table(cols)
+
+
+def _note_probe_rounds(table) -> None:
+    """Traced, record the probe's depth on the enclosing ``kernel/join``
+    span (attrs ``probe_rounds``, ``max_probes``); the read waits for the
+    build only, the probe already dispatched. Untraced, nothing is read."""
+    span = get_tracer().current()
+    if span is None or span.name != "kernel/join":
+        return
+    span.attrs["probe_rounds"] = int(host_copy(table.rounds, "probe_rounds"))
+    span.attrs["max_probes"] = MAX_PROBES
 
 
 def groupby_sum(group_ids, values, num_groups: int):

@@ -393,7 +393,7 @@ def calibrated_rates(sample_rows: int = 1 << 18, force: bool = False) -> dict:
         jax.block_until_ready(out)
         return (time.perf_counter() - t0) / 3
 
-    slots_tbl = ops.build_hash_table(bkeys)
+    table = ops.build_hash_table(bkeys)
     _RATE_CACHE.update({
         "scan": nbytes / timeit(
             lambda k: jnp.sum(jnp.where(k % 3 == 0, k, 0)), keys),
@@ -401,7 +401,7 @@ def calibrated_rates(sample_rows: int = 1 << 18, force: bool = False) -> dict:
         "hash_build": (bkeys.shape[0] * 8.0) / timeit(
             ops.build_hash_table, bkeys),
         "hash_probe": nbytes / timeit(
-            ops.hash_join_indices, keys, bkeys, slots_tbl),
+            ops.hash_join_indices, keys, bkeys, table),
         "merge_join": nbytes / timeit(
             ops.sort_merge_join_indices, keys, bkeys),
         "agg": nbytes / timeit(

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import threading
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -419,24 +420,50 @@ def _hash_table_size(n: int) -> int:
     return max(16, int(2 ** np.ceil(np.log2(4 * n))))
 
 
+class HashTable(NamedTuple):
+    """An open-addressing table over a build side's row indices.
+
+    ``rounds`` (device int32 scalar) is how deep the build placed its keys:
+    a key placed in round ``p`` sits at slot ``(h0 + p) % cap``, and
+    ``p < rounds`` for every placed key, so a probe of ``rounds`` rounds
+    finds all that a deeper one would."""
+
+    slots: jax.Array                  # stored row index, -1 = empty
+    rounds: jax.Array
+
+
+# the build's round budget: the deepest probe a table can ask for
+MAX_PROBES = 16
+
+
 @partial(jax.jit, static_argnames=("max_probes",))
-def build_hash_table(build_keys: jax.Array, max_probes: int = 16):
+def build_hash_table(build_keys: jax.Array,
+                     max_probes: int = MAX_PROBES) -> HashTable:
     """Open-addressing (linear probing) insert of unique build keys.
 
     Parallel insertion: each round, every unplaced key writes its row index
-    to its current probe slot; scatter conflicts resolve last-writer-wins,
-    losers advance to the next probe position. With load factor <= 0.5 this
-    converges in a handful of rounds.
+    to its current probe slot; scatter conflicts resolve max-row-wins,
+    losers advance to the next probe position. Rounds go on while some key
+    is unplaced, at most ``max_probes``; a key still unplaced then stays
+    out of the table. At the load factor <= 0.25 of ``_hash_table_size``
+    dense keys all land in round 0.
+
+    ``rounds`` is one more than the last round that placed a key, or
+    ``max_probes`` if some key was never placed.
     """
     n = build_keys.shape[0]
     cap = _hash_table_size(n)
     bits = int(np.log2(cap))
-    slots = jnp.full((cap,), EMPTY)            # stored row index, -1 = empty
     h0 = _hash(build_keys, bits)
     rows = jnp.arange(n, dtype=jnp.int32)
 
-    def round_(p, carry):
-        slots, placed = carry
+    def unplaced(carry):
+        p, _, placed = carry
+        return jnp.logical_and(p < max_probes,
+                               jnp.logical_not(jnp.all(placed)))
+
+    def round_(carry):
+        p, slots, placed = carry
         pos = (h0 + p) % cap
         # only unplaced keys contending for currently-empty slots
         want = jnp.logical_and(jnp.logical_not(placed), slots[pos] == EMPTY)
@@ -446,17 +473,23 @@ def build_hash_table(build_keys: jax.Array, max_probes: int = 16):
         slots_ext = slots_ext.at[tgt].max(cand)   # max = deterministic winner
         slots = slots_ext[:cap]
         placed = jnp.logical_or(placed, slots[pos] == rows)
-        return slots, placed
+        return p + 1, slots, placed
 
-    slots, _ = jax.lax.fori_loop(0, max_probes, round_,
-                                 (slots, jnp.zeros((n,), bool)))
-    return slots
+    # the loop stops right after the round that placed the last key, or at
+    # max_probes with some key unplaced: either way p is the depth
+    rounds, slots, _ = jax.lax.while_loop(
+        unplaced, round_,
+        (jnp.int32(0), jnp.full((cap,), EMPTY), jnp.zeros((n,), bool)))
+    return HashTable(slots, rounds)
 
 
-@partial(jax.jit, static_argnames=("max_probes",))
+@jax.jit
 def hash_join_indices(probe_keys: jax.Array, build_keys: jax.Array,
-                      slots: jax.Array, max_probes: int = 16):
-    """Probe the hash table. Returns (idx_into_build, found) per probe row."""
+                      table: HashTable):
+    """Probe the hash table, ``table.rounds`` rounds deep (a traced bound:
+    no recompilation as the depth changes). Returns (idx_into_build,
+    found) per probe row."""
+    slots = table.slots
     cap = slots.shape[0]
     bits = int(np.log2(cap))
     h = _hash(probe_keys, bits)
@@ -474,7 +507,7 @@ def hash_join_indices(probe_keys: jax.Array, build_keys: jax.Array,
 
     idx0 = jnp.zeros_like(probe_keys)
     found0 = jnp.zeros(probe_keys.shape, bool)
-    idx, found = jax.lax.fori_loop(0, max_probes, probe, (idx0, found0))
+    idx, found = jax.lax.fori_loop(0, table.rounds, probe, (idx0, found0))
     return idx, found
 
 

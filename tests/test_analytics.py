@@ -28,6 +28,7 @@ from repro.analytics.simulator import SimTask
 from repro.analytics.table import distribute, phantom
 from repro.core.controllers import GlobalController, PrivateController
 from repro.core.decisions import DataDist, DecisionContext
+from repro.kernels import ops as kops
 
 
 def make_tables(rows=2048, keyspace=1024, dim_rows=256, seed=0):
@@ -98,8 +99,8 @@ def test_hash_join_property(seed, rows, dim_rows):
     build = jnp.asarray(rng.permutation(10 * dim_rows)[:dim_rows],
                         jnp.int32)
     probe = jnp.asarray(rng.integers(0, 10 * dim_rows, rows), jnp.int32)
-    slots = ops.build_hash_table(build)
-    idx, found = ops.hash_join_indices(probe, build, slots)
+    table = ops.build_hash_table(build)
+    idx, found = ops.hash_join_indices(probe, build, table)
     build_np, probe_np = np.asarray(build), np.asarray(probe)
     lookup = {int(k): i for i, k in enumerate(build_np)}
     for j in range(rows):
@@ -108,6 +109,126 @@ def test_hash_join_property(seed, rows, dim_rows):
             assert int(idx[j]) == lookup[int(probe_np[j])]
         else:
             assert not bool(found[j])
+
+
+def _home_slots(keys, cap):
+    """The build's and the probe's first slot: the multiplicative hash's
+    top ``log2(cap)`` bits, in numpy."""
+    h = (np.asarray(keys, np.uint64) * int(ops.HASH_MULT)) % 2 ** 32
+    return (h >> (32 - int(np.log2(cap)))).astype(np.int64)
+
+
+def _colliding_keys(n, cap, home=3):
+    """``n`` distinct keys whose first slot in a table of ``cap`` slots is
+    ``home``: each round of the build places one of them."""
+    ks = np.arange(200 * cap)
+    return ks[_home_slots(ks, cap) == home][:n].astype(np.int32)
+
+
+def _full_depth(table):
+    """The same slots probed every one of the build's ``MAX_PROBES``
+    rounds: the probe as deep as the budget allows."""
+    return ops.HashTable(table.slots, jnp.int32(ops.MAX_PROBES))
+
+
+@pytest.mark.parametrize("n", [1, 7, 100, 1000, 18_000, 102_000])
+def test_dense_keys_are_placed_in_one_round(n):
+    """Dense surrogate keys ``0..n-1`` at load <= 0.25 all land in their
+    first slot, so the build records a probe depth of one round."""
+    build = jnp.arange(n, dtype=jnp.int32)
+    table = ops.build_hash_table(build)
+    assert int(table.rounds) == 1
+    probe = jnp.asarray(np.random.default_rng(n).integers(-5, n + 5, 4096),
+                        jnp.int32)
+    idx, found = ops.hash_join_indices(probe, build, table)
+    p = np.asarray(probe)
+    np.testing.assert_array_equal(np.asarray(found), (p >= 0) & (p < n))
+    np.testing.assert_array_equal(np.asarray(idx), np.where(
+        (p >= 0) & (p < n), p, 0))
+
+
+@pytest.mark.parametrize("n_collide", [2, 5, 9])
+def test_colliding_keys_probe_as_deep_as_placed(n_collide):
+    """Keys that share a first slot are placed one per round; the probe
+    runs exactly that deep and equals the full-depth probe, on probes
+    that hit at every displacement and on probes that miss."""
+    cap = kops._hash_table_size(n_collide + 8)
+    hot = _colliding_keys(n_collide, cap)
+    rng = np.random.default_rng(n_collide)
+    others = np.setdiff1d(rng.permutation(50 * cap)[:64], hot)
+    others = others[_home_slots(others, cap) != 3][:8].astype(np.int32)
+    build_np = np.concatenate([hot, others])
+    assert kops._hash_table_size(build_np.size) == cap
+    build = jnp.asarray(build_np)
+    table = ops.build_hash_table(build)
+    rounds = int(table.rounds)
+    assert rounds >= n_collide > 1
+    # a key placed in round p sits p slots past its first slot
+    slots = np.asarray(table.slots)
+    where = {int(r): s for s, r in enumerate(slots) if r >= 0}
+    assert sorted(where) == list(range(build_np.size))
+    depth = [(where[i] - _home_slots(build_np[i:i + 1], cap)[0]) % cap
+             for i in range(build_np.size)]
+    assert max(depth) + 1 == rounds
+    assert sorted(depth[:n_collide]) == list(range(n_collide))
+    # every displacement hits; misses walk the hot cluster and beyond
+    miss_hot = _colliding_keys(n_collide + 4, cap)[n_collide:]
+    misses = np.setdiff1d(np.arange(10 * cap), build_np)[::7][:40]
+    probe_np = np.concatenate([build_np, miss_hot, misses,
+                               build_np[::-1]]).astype(np.int32)
+    probe = jnp.asarray(probe_np)
+    idx, found = ops.hash_join_indices(probe, build, table)
+    ref_idx, ref_found = ops.hash_join_indices(probe, build,
+                                               _full_depth(table))
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ref_idx))
+    np.testing.assert_array_equal(np.asarray(found), np.asarray(ref_found))
+    lookup = {int(k): i for i, k in enumerate(build_np)}
+    np.testing.assert_array_equal(
+        np.asarray(found), [int(k) in lookup for k in probe_np])
+    np.testing.assert_array_equal(
+        np.asarray(idx), [lookup.get(int(k), 0) for k in probe_np])
+
+
+def test_too_small_a_budget_leaves_keys_out_as_before():
+    """A build whose round budget cannot place every key records the whole
+    budget as its depth; the keys it placed are found, the rest are not,
+    as a full-depth probe of the same table finds them."""
+    n = 8
+    cap = kops._hash_table_size(n)
+    build_np = _colliding_keys(n, cap)
+    build = jnp.asarray(build_np)
+    table = ops.build_hash_table(build, max_probes=4)
+    assert int(table.rounds) == 4
+    # the largest row index wins each round: rows 7, 6, 5, 4 are placed
+    assert sorted(int(r) for r in np.asarray(table.slots) if r >= 0) == \
+        [4, 5, 6, 7]
+    probe_np = np.concatenate([build_np, build_np[::-1] + 1])
+    probe = jnp.asarray(probe_np)
+    idx, found = ops.hash_join_indices(probe, build, table)
+    ref_idx, ref_found = ops.hash_join_indices(probe, build,
+                                               _full_depth(table))
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ref_idx))
+    np.testing.assert_array_equal(np.asarray(found), np.asarray(ref_found))
+    placed = {int(k): i for i, k in enumerate(build_np) if i >= 4}
+    np.testing.assert_array_equal(
+        np.asarray(found), [int(k) in placed for k in probe_np])
+    np.testing.assert_array_equal(
+        np.asarray(idx), [placed.get(int(k), 0) for k in probe_np])
+
+
+def test_probe_depth_is_traced_not_compiled():
+    """Tables of one size and different depths share one compiled probe."""
+    cap = kops._hash_table_size(16)
+    dense = jnp.arange(16, dtype=jnp.int32)
+    skewed = jnp.asarray(_colliding_keys(16, cap))
+    probe = jnp.arange(64, dtype=jnp.int32)
+    shallow = ops.build_hash_table(dense)
+    deep = ops.build_hash_table(skewed)
+    assert int(shallow.rounds) == 1 and int(deep.rounds) == 16
+    ops.hash_join_indices(probe, dense, shallow)
+    before = ops.hash_join_indices._cache_size()
+    ops.hash_join_indices(probe, skewed, deep)
+    assert ops.hash_join_indices._cache_size() == before
 
 
 def test_partition_permutation_property():

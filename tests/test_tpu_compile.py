@@ -1,4 +1,5 @@
-"""Compile rehearsals of the query-path Pallas kernels for a TPU v5e chip.
+"""Compile rehearsals of the query-path Pallas kernels, and of the hash
+join's build and probe loops, for a TPU v5e chip.
 
 The TPU compiler is installed even where no chip is attached: compiling for
 a described ``v5e:2x2`` topology raises what the chip's compiler would
@@ -88,3 +89,24 @@ def test_query_path_kernel_compiles_for_v5e(case, compile_for_chip):
     fn, shapes = CASES[case]
     compiled = compile_for_chip(fn, *shapes)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the broadcast hash join at SF10: the item table's 102,000 keys (a
+# 2^19-slot table) and one fact partition of 7.2M probe rows; the probe's
+# depth is a traced bound, a while loop on the chip
+ITEM_ROWS, FACT_PART_ROWS = 102_000, 7_200_248
+HASH_CASES = {
+    "build": (kops.build_hash_table, [((ITEM_ROWS,), jnp.int32)]),
+    "probe": (
+        lambda pk, bk, slots, rounds: kops.hash_join_indices(
+            pk, bk, kops.HashTable(slots, rounds)),
+        [((FACT_PART_ROWS,), jnp.int32), ((ITEM_ROWS,), jnp.int32),
+         ((kops._hash_table_size(ITEM_ROWS),), jnp.int32), ((), jnp.int32)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HASH_CASES))
+def test_hash_join_compiles_for_v5e(case, compile_for_chip):
+    fn, shapes = HASH_CASES[case]
+    compiled = compile_for_chip(fn, *shapes)
+    assert "while" in compiled.as_text()
