@@ -5,18 +5,26 @@ configuration and its traffic mix; each lives in a file of its own:
 
 * ``bench/configs/<config>.json``  — the deployment: table sizes, nodes,
   slots, invoker, the guarantee (the path is the configuration's ``file``);
+  its ``app`` key names the application (default ``tpcds_join_agg``);
+* ``bench/apps/<app>.py``          — the application: its tables, the query
+  each loop submits, its reference, control and error (``drive.py`` says
+  what an app module provides);
 * ``bench/traffic/<traffic>.json`` — the mix: key law, strategies, loop
   kind, tenants' priorities (read by the one generator in ``drive.py``);
+* ``bench/loops/<loop>.py``        — the loop a traffic names: how a unit's
+  queries are submitted and stamped;
 * ``bench/workloads/<cell>.json``  — the cell's correctness limit;
 * ``bench/metrics/<metric>.py``    — one reader per metric (``readers.py``).
 
-Adding a cell, a mix or a metric adds files and entries; no existing file
-changes.
+Adding a cell, a mix, an application, a loop or a metric adds files and
+entries; no existing file changes.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import json
+import sys
 from dataclasses import dataclass, field
 from pathlib import Path
 
@@ -33,6 +41,19 @@ class Cell:
     limits: dict
     end_to_end: list = field(default_factory=list)   # metric entries
     per_layer: list = field(default_factory=list)
+
+
+def load_module(kind: str, name: str):
+    """The module ``bench/<kind>/<name>.py``, loaded by its path."""
+    path = BENCH_DIR / kind / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"no {kind[:-1]} {name!r}: {path} is missing")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{name.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod    # a dataclass looks its module up there
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _applies(metric: dict, cell: str) -> bool:

@@ -4,11 +4,11 @@ A traffic file (``bench/traffic/<name>.json``) is data only. Its keys:
 
 * ``keys``       — the fact key law: ``{"law": "uniform"}`` or
                    ``{"law": "zipf", "s": 1.5}`` (see ``gen.py``);
-* ``loop``       — ``"closed"``: one client, each query submitted when the
-                   last one returned, through ``execute_query_runtime``;
-                   ``"waves"``: every tenant's query submitted at once to
-                   one ``QueryScheduler``, the next wave when the last one
-                   has ended;
+* ``loop``       — the loop ``bench/loops/<loop>.py``. ``"closed"``: one
+                   client, each query submitted when the last one
+                   returned; ``"waves"``: every tenant's query submitted at
+                   once to one ``QueryScheduler``, the next wave when the
+                   last one has ended;
 * ``strategies`` — join strategies; a closed loop uses the first, a wave
                    gives tenant ``i`` of wave ``w`` entry ``(i + w) % n``;
 * ``priorities`` — tenant ``i``'s priority is entry ``i % n``;
@@ -18,34 +18,49 @@ A traffic file (``bench/traffic/<name>.json``) is data only. Its keys:
                    reads the free slots), so one wave may not meet every
                    shape the window will.
 
+A configuration's ``app`` key names its application,
+``bench/apps/<app>.py`` (default ``tpcds_join_agg``). An app module
+provides:
+
+* ``make_tenants(config, traffic, seed)`` — each tenant's tables, made on
+  the device from the seed and ready;
+* ``input_rows(config)`` — the input rows of one query (``QueryRec``);
+* ``closed(dep, tenant, app, strategy, priority)`` — one query of a closed
+  loop, not yet run: ``run()`` returns its answer on the host, and
+  ``decisions()`` the ``(stage, func)`` plan it bound, also after a failure;
+* ``job(dep, tenant, app, strategy, priority)`` — the same query as a job
+  for a ``QueryScheduler``, and ``answer(raw)`` — a job's answer on the host;
+* ``reference(config, tenant)`` — the float64 answer, from the tenant's
+  tables alone, importing nothing of the program;
+* ``control(config, tenant)`` — the reference one precision below the
+  configuration's, which ``bench/calibrate.py`` reads;
+* ``error(got, ref)`` — the number ``bench/run.py`` compares with the
+  cell's ``rel_err`` limit (``inf`` for a missing answer).
+
+A loop module provides ``run_unit(dep, unit) -> list[QueryRec]``: it asks
+the app for each query or job and stamps the records.
+
 Each query of a run is a fresh application over the run's tables. Once it
-has returned, its group sums are kept for the check that follows the
-window, and its store and invocation records are released, so memory stays
-flat however long the window runs.
+has returned, its answer is kept for the check that follows the window,
+and its store and invocation records are released, so memory stays flat
+however long the window runs.
 """
 
 from __future__ import annotations
 
 import time
-import traceback
 from dataclasses import dataclass, field
 
-import numpy as np
+from benchlib.cell import load_module
 
-from benchlib import gen
-
-
-@dataclass
-class Tenant:
-    fact_parts: list            # device column dicts, one per node
-    dim_parts: list
-    fact: object = None         # the program's DistTable views of them
-    dim: object = None
+DEFAULT_APP = "tpcds_join_agg"
 
 
 @dataclass
 class QueryRec:
-    """One query as the harness saw it. Times are ``time.perf_counter()``."""
+    """One query as the harness saw it. Times are ``time.perf_counter()``.
+    ``fact_rows`` counts the input rows of the query, whatever its app
+    (``rows_per_s`` reads it by this name)."""
 
     unit: int
     app: str
@@ -55,7 +70,7 @@ class QueryRec:
     submitted: float
     done: float = 0.0
     fact_rows: int = 0
-    sums: object = None
+    answer: object = None
     error: str | None = None
     decisions: tuple = ()
     fn_s: float = 0.0            # billed function-seconds, retries included
@@ -70,41 +85,25 @@ class QueryRec:
 
 
 class Deployment:
-    """A configuration's tables, made on the device from the seed, and the
-    shared runtime its queries run on."""
+    """A configuration's tenants, their tables made on the device from the
+    seed by the configuration's app, and the shared runtime its queries
+    run on."""
 
     def __init__(self, config: dict, traffic: dict, seed: int):
-        import jax
-
-        from repro.analytics.table import DistTable, Table
         from repro.core.controllers import GlobalController
         from repro.runtime import Runtime
 
         self.config, self.traffic = config, traffic
-        fact_nodes = int(config["fact_nodes"])
-        dim_nodes = int(config["dim_nodes"])
-        plan = gen.fact_plan(int(config["fact_rows"]), fact_nodes,
-                             int(config["dim_rows"]), traffic["keys"],
-                             float(config["assumed"]["filter_pass_share"]))
-        self.tenants = []
-        for i in range(int(config.get("tenants", 1))):
-            t = Tenant(gen.make_fact(seed + i, plan),
-                       gen.make_dim(seed + i, int(config["dim_rows"]),
-                                    dim_nodes, int(config["num_groups"])))
-            t.fact = DistTable("A", {n: Table(dict(p)) for n, p
-                                     in enumerate(t.fact_parts)})
-            t.dim = DistTable("B", {n: Table(dict(p)) for n, p
-                                    in enumerate(t.dim_parts)})
-            self.tenants.append(t)
-        jax.block_until_ready([t.fact_parts + t.dim_parts
-                               for t in self.tenants])
+        self.app = load_module("apps", config.get("app", DEFAULT_APP))
+        self.loop = load_module("loops", traffic["loop"])
+        self.tenants = self.app.make_tenants(config, traffic, seed)
         nodes = int(config["nodes"])
         gc = GlobalController({n: int(config["slots_per_node"])
                                for n in range(nodes)})
         self.runtime = Runtime(gc, invoker=config["invoker"],
                                max_workers=int(config["max_workers"]))
         # the host's clock at the moment the scheduler hands a finished
-        # query's state back (its sums are captured just before)
+        # query's state back (its answer is captured just before)
         self.released: dict[str, float] = {}
         release = self.runtime.release
 
@@ -115,82 +114,8 @@ class Deployment:
         self.runtime.release = stamped_release
 
     @property
-    def fact_rows(self) -> int:
-        return int(self.config["fact_rows"])
+    def input_rows(self) -> int:
+        return self.app.input_rows(self.config)
 
     def run_unit(self, unit: int) -> list[QueryRec]:
-        loop = self.traffic["loop"]
-        if loop == "closed":
-            return self._closed(unit)
-        if loop == "waves":
-            return self._wave(unit)
-        raise ValueError(f"unknown loop {loop!r}")
-
-    def _closed(self, unit: int) -> list[QueryRec]:
-        from repro.analytics import (QueryStrategy, build_query_workflow,
-                                     execute_query_runtime)
-
-        t = self.tenants[0]
-        name = self.traffic["strategies"][0]
-        strategy = QueryStrategy(name)
-        workflow = build_query_workflow(strategy)
-        app = f"q{unit}"
-        rec = QueryRec(unit, app, 0, name, int(self.traffic["priorities"][0]),
-                       time.perf_counter(), fact_rows=self.fact_rows)
-        try:
-            sums, _ = execute_query_runtime(
-                t.fact, t.dim, strategy, runtime=self.runtime, app=app,
-                priority=rec.priority, workflow=workflow,
-                num_groups=int(self.config["num_groups"]),
-                pipeline=bool(self.config["pipeline"]))
-            rec.sums = np.asarray(sums, np.float64)
-        except Exception as e:  # noqa: BLE001 - a failed query is counted
-            traceback.print_exc()
-            rec.error = f"{type(e).__name__}: {e}"
-        rec.done = time.perf_counter()
-        if workflow.last_run is not None:
-            rec.decisions = tuple((n, d.func)
-                                  for n, d in workflow.last_run.sequence)
-        recs = self.runtime.metrics.for_app(app)
-        rec.fn_s = sum(r.seconds for r in recs)
-        rec.invocations = len(recs)
-        rec.rows_actual = sum(r.rows_actual for r in recs)
-        rec.rows_padded = sum(r.rows_padded for r in recs)
-        self.runtime.release(app)
-        self.released.pop(app, None)
-        self.runtime.metrics.clear(app)
-        return [rec]
-
-    def _wave(self, unit: int) -> list[QueryRec]:
-        from repro.runtime import QueryJob, QueryScheduler
-
-        tr = self.traffic
-        strategies, priorities = tr["strategies"], tr["priorities"]
-        sched = QueryScheduler(self.runtime, policy=tr["policy"],
-                               release_stores=True, compact_metrics=True)
-        recs = []
-        for i, t in enumerate(self.tenants):
-            name = strategies[(i + unit) % len(strategies)]
-            prio = int(priorities[i % len(priorities)])
-            app = f"w{unit}t{i}"
-            sched.submit(QueryJob(app, t.fact, t.dim, name, priority=prio,
-                                  num_groups=int(self.config["num_groups"])))
-            recs.append(QueryRec(unit, app, i, name, prio, 0.0,
-                                 fact_rows=self.fact_rows))
-        submitted = time.perf_counter()
-        results = sched.run()
-        for rec in recs:
-            res = results[rec.app]
-            rec.submitted = submitted
-            rec.done = self.released.pop(rec.app, time.perf_counter())
-            if res.ok:
-                rec.sums = np.asarray(res.sums, np.float64)
-            else:
-                rec.error = f"{type(res.error).__name__}: {res.error}"
-            rec.decisions = tuple((n, d.func) for n, d in res.decisions)
-            stages = res.stages.values()
-            rec.fn_s = sum(m.seconds for m in stages)
-            rec.invocations = sum(m.invocations for m in stages)
-            rec.rows_actual = sum(m.rows_actual for m in stages)
-            rec.rows_padded = sum(m.rows_padded for m in stages)
-        return recs
+        return self.loop.run_unit(self, unit)

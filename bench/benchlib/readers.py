@@ -9,10 +9,9 @@ for a share of a roofline or of a peak it could not measure.
 
 from __future__ import annotations
 
-import importlib.util
 from dataclasses import dataclass, field
 
-from benchlib.cell import BENCH_DIR
+from benchlib.cell import load_module
 
 
 @dataclass
@@ -29,12 +28,7 @@ class RunView:
 
 
 def load_reader(name: str):
-    path = BENCH_DIR / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module("metrics", name).read
 
 
 def read_all(metrics: list, run: RunView) -> dict:

@@ -4,17 +4,18 @@
 
 For each seed, in one process on the chip: the cell's tables made on the
 device, ``--units`` units of the cell's traffic through the program (the
-first seed's first unit compiles), and for every query two readings of the
-number that ``bench/run.py`` compares, the relative error of the worst
-group against the float64 reference of its tables:
+first seed's first unit compiles), and two readings of the number that
+``bench/run.py`` compares, the app's error against its float64 reference
+of each tenant's tables (``bench/apps/<app>.py``):
 
-* ``program`` — the program's group sums (a run's number is the largest
+* ``program`` — the program's answers (a run's number is the largest
   over its queries: the lower reading of the limit is the largest of these
   over all seeds);
-* ``control`` — the reference itself computed with bfloat16 values and
-  products in the program's place (``oracle.control_sums``): the largest
-  over a seed's tenants is what a run of the control would read, and the
-  upper reading is the smallest of those over the seeds.
+* ``control`` — the app's control, the reference one precision below the
+  configuration's, in the program's place (for ``tpcds_join_agg``,
+  bfloat16 values and products): the largest over a seed's tenants is
+  what a run of the control would read, and the upper reading is the
+  smallest of those over the seeds.
 
 Prints one JSON line per seed, then a summary line with both readings.
 """
@@ -32,20 +33,15 @@ sys.path.insert(0, str(BENCH_DIR.parent / "src"))
 
 
 def readings(dep, units: int, first_unit: int = 0) -> dict:
-    from benchlib import oracle
-
-    G = int(dep.config["num_groups"])
+    app = dep.app
     queries = []
     for u in range(first_unit, first_unit + units):
         queries += dep.run_unit(u)
     program, control = [], []
     for i, t in enumerate(dep.tenants):
-        fact = oracle.host_columns(t.fact_parts, ("key", "v0", "v1"))
-        dim = oracle.host_columns(t.dim_parts, ("key", "cat"))
-        ref = oracle.reference_sums(fact, dim, G)
-        control.append(oracle.relative_error(
-            oracle.control_sums(fact, dim, G), ref))
-        program += [oracle.relative_error(q.sums, ref)
+        ref = app.reference(dep.config, t)
+        control.append(app.error(app.control(dep.config, t), ref))
+        program += [app.error(q.answer, ref)
                     for q in queries if q.tenant == i]
     return {"program": max(program), "control": max(control),
             "errors": [q.error for q in queries if q.error]}

@@ -9,15 +9,16 @@ A run, in order:
 
 1. set-up (``setup_s``, from the start of the process): JAX and the chip,
    the compile cache at the checkout's fixed path, the cell's tables made
-   on the device from ``--seed``, and the warm-up units of the cell's
-   traffic (queries or waves; ``warmup_units``, default 1), which compile
-   or load from the cache every program the window runs;
+   on the device from ``--seed`` by the configuration's app
+   (``bench/apps/``), and the warm-up units of the cell's traffic (queries
+   or waves of its loop, ``bench/loops/``; ``warmup_units``, default 1),
+   which compile or load from the cache every program the window runs;
 2. the window: whole units of the traffic until ``--seconds`` have passed,
    ending at the end of the last one. No program compiles here; the count
    is printed;
 3. the check, once the window has closed and the peak memory is read:
-   every query's group sums against the float64 reference of its own
-   tables (``benchlib/oracle.py``);
+   every query's answer against the app's float64 reference of its own
+   tables, by the app's error;
 4. the metrics, each from its reader in ``bench/metrics/``: the cell's
    end-to-end metrics with ``--trace 0`` (the program's tracer off, no
    profiler), its per-layer metrics with ``--trace 1`` (tracer on, the
@@ -134,26 +135,20 @@ def run_window(dep, seconds: float, traced: bool, trace_dir: str | None):
 
 
 def check(dep, queries, warmup, limits) -> dict:
-    """Every query's group sums against the reference of its tenant's
+    """Every query's answer against the app's reference of its tenant's
     tables. Returns the checks, each ``{"value", "limit"}``, and the
     failed count."""
-    from benchlib import oracle
-
-    G = int(dep.config["num_groups"])
-    refs = []
-    for t in dep.tenants:
-        fact = oracle.host_columns(t.fact_parts, ("key", "v0", "v1"))
-        dim = oracle.host_columns(t.dim_parts, ("key", "cat"))
-        refs.append(oracle.reference_sums(fact, dim, G))
+    app = dep.app
+    refs = [app.reference(dep.config, t) for t in dep.tenants]
     limit = float(limits["rel_err"])
     errs, failed = [], 0
     for q in queries:
-        err = oracle.relative_error(q.sums, refs[q.tenant])
+        err = app.error(q.answer, refs[q.tenant])
         errs.append(err)
         if q.error is not None or not err <= limit:
             failed += 1
     warm_bad = sum(1 for q in warmup if q.error is not None or
-                   not oracle.relative_error(q.sums, refs[q.tenant]) <= limit)
+                   not app.error(q.answer, refs[q.tenant]) <= limit)
     return {"failed": failed, "errors": [q.error for q in queries + warmup
                                          if q.error][:3],
             "checks": {
